@@ -23,7 +23,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 N, MEAN, M2, MIN, MAX = range(5)
@@ -194,7 +194,7 @@ def make_distributed_ad_step(
         out_specs=(table_spec, P(ax)),
         # pallas_call has no replication rule; the specs above are still
         # sound (outputs are psum-reduced over the axes they omit).
-        check_rep=not use_pallas,
+        check_vma=not use_pallas,
     )
     return jax.jit(fn)
 

@@ -4,8 +4,8 @@ The paper's on-node AD module folds each trace frame into per-function
 runtime statistics and labels events against μ±ασ (§III-B1).  On TPU the
 segment-reduction is *rethought for the MXU*: instead of scatter/gather
 (slow, serializing on TPU), a block of events becomes a one-hot matrix
-(events × functions) and the statistics are three matmuls on the systolic
-array:
+(events × functions) and the statistics are matmuls on the systolic array
+(each f32 operand split into three bf16 terms, see ``_bf16_terms``):
 
     n_f   = 1ᵀ  · onehot        Σx_f = xᵀ · onehot        Σx²_f = (x²)ᵀ · onehot
 
@@ -14,8 +14,15 @@ back through the MXU).  min/max fall to the VPU via masked reductions.
 
 Grid: 1-D over event blocks; the (F, 5) accumulator table lives in VMEM
 scratch across grid steps and is flushed to the output on the last step.
-Blocks: EB=512 events; F ≤ 2048 functions per table tile (the (EB, F)
-one-hot peaks at 512×2048×4 B = 4 MiB of VMEM).
+Blocks: EB=1024 events; F ≤ 2048 functions per table tile (each f32
+(EB, F) temporary of the min/max pass takes 1024×2048×4 B = 8 MiB of VMEM,
+the bf16 one-hot half that).
+
+Mosaic layout rules the kernel is shaped by: a 1-D int32/f32 operand gets
+XLA's ``T(1024)`` tiling on TPU, so a 1-D event block must hold 1024 events
+(or the whole, shorter, event vector); and a boolean vector cannot be
+widened to int8 in-kernel, so labels leave the kernel as int32 and are
+narrowed to int8 outside it.
 
 Padding: fid < 0 marks padding events (weight 0, label 0).
 
@@ -43,6 +50,20 @@ NEG = -1e30
 POS = 1e30
 
 
+def _bf16_terms(v: jnp.ndarray):
+    """Split f32 ``v`` into three bf16 terms whose f32 sum is ``v``.
+
+    A 0/1 one-hot times a bf16 term is exact on the MXU, so one one-pass
+    bf16 matmul per term (f32 accumulation) gathers or segment-sums f32
+    values exactly; a one-pass f32 matmul would round them to 8 bits.
+    """
+    hi = v.astype(jnp.bfloat16)
+    r = v - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
 def _moments_kernel(
     fids_ref, durs_ref, table_ref, out_ref, labels_ref, acc_ref,
     *, alpha: float, min_count: float, F: int, fid_offset: int,
@@ -59,32 +80,38 @@ def _moments_kernel(
     fids = fids_ref[...] - fid_offset  # (EB,) int32, rebased to shard rows
     x = durs_ref[...]  # (EB,) f32
     valid = (fids >= 0) & (fids < F)  # padding + out-of-shard events drop out
-    w = valid.astype(jnp.float32)
     EB = fids.shape[0]
 
-    # one-hot on the MXU: (EB, F)
+    # one-hot on the MXU: (EB, F); 0/1 is exact in bf16.  Padding and
+    # out-of-shard fids fall outside [0, F) and so match no column.
     iota_f = jax.lax.broadcasted_iota(jnp.int32, (EB, F), 1)
-    onehot = (iota_f == fids[:, None]).astype(jnp.float32) * w[:, None]
+    hit = iota_f == fids[:, None]
+    onehot = hit.astype(jnp.bfloat16)
 
     # ---- labeling against the PREVIOUS global table (paper semantics) ----
-    tbl = table_ref[...]  # (F, 5): n, sum, sumsq, min, max
-    n_prev = jnp.dot(onehot, tbl[:, 0], preferred_element_type=jnp.float32)
-    s_prev = jnp.dot(onehot, tbl[:, 1], preferred_element_type=jnp.float32)
-    q_prev = jnp.dot(onehot, tbl[:, 2], preferred_element_type=jnp.float32)
+    # (EB, F) x (F, 3) matmuls read every event's n, Σx, Σx² back.
+    rows = sum(
+        jnp.dot(onehot, t, preferred_element_type=jnp.float32)
+        for t in _bf16_terms(table_ref[:, :3])
+    )  # (EB, 3)
+    n_prev, s_prev, q_prev = rows[:, 0], rows[:, 1], rows[:, 2]
     mu = jnp.where(n_prev > 0, s_prev / jnp.maximum(n_prev, 1.0), 0.0)
     var = jnp.maximum(
         jnp.where(n_prev > 1, q_prev / jnp.maximum(n_prev, 1.0) - mu * mu, 0.0), 0.0
     )
     sd = jnp.sqrt(var)
     out = ((x > mu + alpha * sd) | (x < mu - alpha * sd)) & (n_prev >= min_count) & valid
-    labels_ref[...] = out.astype(jnp.int8)
+    labels_ref[...] = out.astype(jnp.int32)
 
-    # ---- moment accumulation (3 MXU matmuls) -----------------------------
-    stacked = jnp.stack([w, x * w, x * x * w], axis=0)  # (3, EB)
-    sums = jnp.dot(stacked, onehot, preferred_element_type=jnp.float32)  # (3, F)
-    masked = jnp.where(onehot > 0, x[:, None], POS)
+    # ---- moment accumulation on the MXU ----------------------------------
+    stacked = jnp.stack([jnp.ones_like(x), x, x * x], axis=0)  # (3, EB)
+    sums = sum(
+        jnp.dot(t, onehot, preferred_element_type=jnp.float32)
+        for t in _bf16_terms(stacked)
+    )  # (3, F)
+    masked = jnp.where(hit, x[:, None], POS)
     mins = jnp.min(masked, axis=0)
-    masked = jnp.where(onehot > 0, x[:, None], NEG)
+    masked = jnp.where(hit, x[:, None], NEG)
     maxs = jnp.max(masked, axis=0)
     acc_ref[:, 0] += sums[0]
     acc_ref[:, 1] += sums[1]
@@ -104,7 +131,7 @@ def moments_and_labels(
     *,
     alpha: float = 6.0,
     min_count: float = 10.0,
-    block_events: int = 512,
+    block_events: int = 1024,
     fid_offset: int = 0,
     interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -140,9 +167,9 @@ def moments_and_labels(
         ],
         out_shape=[
             jax.ShapeDtypeStruct((F, 5), jnp.float32),
-            jax.ShapeDtypeStruct((N + pad,), jnp.int8),
+            jax.ShapeDtypeStruct((N + pad,), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((F, 5), jnp.float32)],
         interpret=interpret,
     )(fids, durs.astype(jnp.float32), table_sums.astype(jnp.float32))
-    return delta, labels[:N]
+    return delta, labels[:N].astype(jnp.int8)

@@ -1,9 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this dev container) kernels execute in interpret mode; on real TPU
-backends ``interpret=False`` compiles them to Mosaic.  ``ops`` also does the
-shape hygiene (head-dim lane padding, event padding, format conversion to
-the jax_ad (n, mean, M2, min, max) table layout).
+On the CPU backend (``JAX_PLATFORMS=cpu``, the test suite) kernels run in
+the Pallas interpreter; on any other backend they compile to Mosaic, so a
+run that finds no TPU fails instead of interpreting quietly.  ``ops`` also
+does the shape hygiene (head-dim lane padding, event padding, format
+conversion to the jax_ad (n, mean, M2, min, max) table layout).
 """
 from __future__ import annotations
 
@@ -20,7 +21,7 @@ from . import moments as _mo
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 # ------------------------------------------------------------------ moments

@@ -49,9 +49,7 @@ def probe_costs(cfg, shape: str, mesh, opts_kw, microbatch: int) -> dict:
         opts = StepOptions(**{**opts_kw, "probe": True, "microbatch": microbatch})
         cell = make_cell(pcfg, shape, mesh, opts)
         compiled = cell.lower().compile()
-        from repro.compat import cost_analysis as _ca_compat
-
-        ca = _ca_compat(compiled)
+        ca = compiled.cost_analysis()
         coll = R.collective_bytes(compiled.as_text())
         vals[npd] = {
             "flops": float(ca.get("flops", 0.0)),
@@ -140,9 +138,7 @@ def run_cell(
 
     # ---- cost ----------------------------------------------------------
     try:
-        from repro.compat import cost_analysis as _ca_compat
-
-        ca = _ca_compat(compiled)
+        ca = compiled.cost_analysis()
         rec["cost"] = {
             "flops": float(ca.get("flops", -1.0)),
             "bytes_accessed": float(ca.get("bytes accessed", -1.0)),

@@ -26,9 +26,10 @@ def make_production_mesh(*, multi_pod: bool = False):
             "must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import"
         )
-    from repro.compat import make_mesh as _make_mesh
-
-    return _make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices[:n],
+    )
 
 
 def batch_axes(mesh) -> Tuple[str, ...]:

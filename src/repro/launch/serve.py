@@ -6,8 +6,9 @@ Per-phase tracing (prefill/decode/detokenize) streams to the monitor; decode
 step-time anomalies (e.g. a slow host) surface exactly like the paper's
 workflow delays.
 
-Usage (CPU dev scale):
-  python -m repro.launch.serve --arch gemma-2b --requests 8 --max-new 16
+Usage:
+  python -m repro.launch.serve --requests 8 --max-new 16          # smoke widths
+  python -m repro.launch.serve --full --batch 8 --prompt-len 512   # published widths
 """
 from __future__ import annotations
 
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import StepOptions, build_decode_step, build_prefill_step, make_shard_ctx
 from repro.models import model as M
 from repro.models.common import init_params
@@ -39,7 +41,7 @@ class Request:
 
 
 def serve(
-    arch: str = "gemma-2b",
+    arch: str = "granite_moe_1b_a400m",
     smoke: bool = True,
     n_requests: int = 8,
     batch: int = 4,
@@ -54,8 +56,16 @@ def serve(
     ctx = make_shard_ctx(cfg, None, batch, opts)
     max_seq = prompt_len + max_new
     params = init_params(cfg, jax.random.key(seed))
-    prefill_fn = jax.jit(build_prefill_step(cfg, ctx, opts, max_seq=max_seq))
-    decode_fn = jax.jit(build_decode_step(cfg, ctx, opts), donate_argnums=(1,))
+    # Both steps compile before the clock starts: tok_per_s excludes it.
+    t_compile = time.perf_counter()
+    prefill = build_prefill_step(cfg, ctx, opts, max_seq=max_seq)
+    prompt_spec = {"tokens": jax.ShapeDtypeStruct((batch, prompt_len), jnp.int32)}
+    prefill_fn = jax.jit(prefill).lower(params, prompt_spec).compile()
+    cache_spec = jax.eval_shape(prefill, params, prompt_spec)[1]
+    decode_fn = jax.jit(build_decode_step(cfg, ctx, opts), donate_argnums=(1,)).lower(
+        params, cache_spec, jax.ShapeDtypeStruct((batch, 1), jnp.int32)
+    ).compile()
+    compile_s = time.perf_counter() - t_compile
 
     own_monitor = monitor is None
     monitor = monitor or ChimbukoMonitor(num_funcs=16, min_samples=8)
@@ -68,12 +78,11 @@ def serve(
     ]
     finished: List[Request] = []
     step = 0
+    next_tok = None
     t_start = time.perf_counter()
     tokens_out = 0
-    while pending or finished is None:
+    while pending:
         wave, pending = pending[:batch], pending[batch:]
-        if not wave:
-            break
         with tracer.span("serve/prefill"):
             prompts = np.stack([r.prompt for r in wave])
             if len(wave) < batch:  # pad the wave to the compiled batch
@@ -93,10 +102,13 @@ def serve(
             step += 1
         finished.extend(wave)
         monitor.ingest(tracer.drain(step))
+    jax.block_until_ready(next_tok)
     dt = time.perf_counter() - t_start
     out = {
         "requests": len(finished),
         "tokens": tokens_out,
+        "compile_s": compile_s,
+        "serve_s": dt,
         "tok_per_s": tokens_out / dt if dt > 0 else 0.0,
         "monitor": monitor.summary(),
         "samples": [r.out[:8] for r in finished[:3]],
@@ -108,14 +120,17 @@ def serve(
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="gemma-2b")
+    ap.add_argument("--arch", default="granite_moe_1b_a400m")
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="published widths instead of the smoke config")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
     out = serve(
-        arch=args.arch, n_requests=args.requests, batch=args.batch,
+        arch=args.arch, smoke=args.smoke, n_requests=args.requests, batch=args.batch,
         prompt_len=args.prompt_len, max_new=args.max_new,
     )
     print(json.dumps(out, indent=2))

@@ -12,9 +12,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro import configs
 from repro.data.pipeline import batch_spec as data_batch_spec

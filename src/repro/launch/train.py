@@ -8,7 +8,7 @@ data stream is a pure function of (seed, step)), optional failure injection
 to exercise the restart path.
 
 Usage (CPU dev scale):
-  python -m repro.launch.train --arch gemma-2b --smoke --steps 60 \
+  python -m repro.launch.train --smoke --steps 60 \
       --global-batch 8 --seq 64 --ckpt-dir /tmp/ckpt --monitor-dir /tmp/mon
 """
 from __future__ import annotations
@@ -25,6 +25,7 @@ import numpy as np
 from repro import configs
 from repro.checkpoint import ckpt as CK
 from repro.data.pipeline import DataShard, SyntheticStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import StepOptions, build_train_step, make_shard_ctx, make_train_state
 from repro.optim.adamw import OptConfig
 from repro.trace.monitor import ChimbukoMonitor
@@ -33,7 +34,7 @@ from repro.viz.server import VizServer
 
 
 def train(
-    arch: str = "gemma-2b",
+    arch: str = "granite_moe_1b_a400m",
     smoke: bool = True,
     steps: int = 60,
     global_batch: int = 8,
@@ -192,7 +193,7 @@ def train(
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="gemma-2b")
+    ap.add_argument("--arch", default="granite_moe_1b_a400m")
     ap.add_argument("--smoke", action="store_true", default=True)
     ap.add_argument("--full", dest="smoke", action="store_false")
     ap.add_argument("--steps", type=int, default=60)
@@ -243,6 +244,7 @@ def main():
     args = ap.parse_args()
     if args.export_trace and not args.monitor_dir:
         ap.error("--export-trace needs --monitor-dir (trace.json lives there)")
+    enable_compile_cache()
 
     kw = dict(
         arch=args.arch, smoke=args.smoke, steps=args.steps,

@@ -17,8 +17,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from repro.compat import shard_map
+from jax import shard_map
 
 from .common import ModelConfig
 

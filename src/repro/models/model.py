@@ -18,9 +18,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
 
 from . import layers as L
 from .common import DENSE, FULL, MAMBA, MLA, MOE, NONE, SWA, LayerSpec, ModelConfig

@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 from repro import configs
-from repro.compat import cost_analysis
 from repro.launch import roofline as R
 
 
@@ -20,14 +19,14 @@ def test_cost_analysis_counts_loop_bodies_once():
         return jax.lax.scan(lambda c, _: (c @ c, None), x, None, length=n)[0]
 
     x = jnp.ones((256, 256))
-    f4 = cost_analysis(jax.jit(f, static_argnums=1).lower(x, 4).compile())["flops"]
-    f8 = cost_analysis(jax.jit(f, static_argnums=1).lower(x, 8).compile())["flops"]
+    f4 = jax.jit(f, static_argnums=1).lower(x, 4).compile().cost_analysis()["flops"]
+    f8 = jax.jit(f, static_argnums=1).lower(x, 8).compile().cost_analysis()["flops"]
     assert f4 == f8  # loop body counted once regardless of trip count
     # unrolled scan counts every iteration
     def fu(x, n):
         return jax.lax.scan(lambda c, _: (c @ c, None), x, None, length=n, unroll=True)[0]
 
-    u8 = cost_analysis(jax.jit(fu, static_argnums=1).lower(x, 8).compile())["flops"]
+    u8 = jax.jit(fu, static_argnums=1).lower(x, 8).compile().cost_analysis()["flops"]
     assert u8 >= 7.5 * f4 / 8 * 8  # ≈ 8 bodies counted
 
 
@@ -87,16 +86,14 @@ from repro import configs
 from repro.launch.steps import StepOptions, make_cell
 from repro.launch.dryrun import probe_costs
 
-from repro.compat import make_mesh
-mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 3)
 configs.SHAPES["mini_train"] = configs.ShapeCell("mini_train", 64, 8, "train")
 cfg = configs.smoke("gemma2-2b")  # period 2, smoke n_layers = 4 (2 periods)
 probe = probe_costs(cfg, "mini_train", mesh, {}, 1)
 
 # ground truth: full model with every scan unrolled, cost counted directly
 full = make_cell(cfg, "mini_train", mesh, StepOptions(probe=True, microbatch=1))
-from repro.compat import cost_analysis
-ca = cost_analysis(full.lower().compile())
+ca = full.lower().compile().cost_analysis()
 direct = float(ca["flops"])
 extrap = probe["flops"]
 rel = abs(extrap - direct) / direct
@@ -121,8 +118,7 @@ import jax
 import dataclasses
 from repro import configs
 from repro.launch.steps import StepOptions, make_cell
-from repro.compat import make_mesh
-mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 3)
 configs.SHAPES["mini"] = configs.ShapeCell("mini", 64, 8, "train")
 configs.SHAPES["mini_dec"] = configs.ShapeCell("mini_dec", 64, 8, "decode")
 for arch in ("jamba-v0.1-52b", "qwen3-moe-30b-a3b", "minicpm3-4b"):

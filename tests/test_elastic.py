@@ -15,7 +15,7 @@ from repro.launch import sharding as SH
 from repro.launch.steps import StepOptions, build_train_step, make_shard_ctx, make_train_state
 from repro.optim.adamw import OptConfig
 
-from repro.compat import make_mesh
+AUTO = (jax.sharding.AxisType.Auto,) * 2
 
 cfg = configs.smoke("gemma-2b")
 opts = StepOptions(ce_chunk=512, opt=OptConfig(peak_lr=1e-3, warmup_steps=5))
@@ -37,7 +37,7 @@ state0 = make_train_state(cfg, 0)
 _, ref_losses = run_steps(None, make_train_state(cfg, 0), 0, 12)
 
 # phase 1: mesh A = (4 data, 2 model)
-mesh_a = make_mesh((4, 2), ("data", "model"))
+mesh_a = jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO)
 sh_a = {
     "params": SH.param_shardings(cfg, jax.eval_shape(lambda: state0["params"]), mesh_a),
 }
@@ -46,7 +46,7 @@ state, l_a = run_steps(mesh_a, state, 0, 6)
 CK.save("/tmp/elastic_ck", 6, state)
 
 # phase 2 ("after node loss"): mesh B = (2 data, 4 model), restored + resharded
-mesh_b = make_mesh((2, 4), ("data", "model"))
+mesh_b = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO)
 target = jax.eval_shape(functools.partial(make_train_state, cfg))
 shards_b = {
     "params": SH.param_shardings(cfg, target["params"], mesh_b),

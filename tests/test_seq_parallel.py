@@ -14,8 +14,7 @@ from repro.models import model as M
 from repro.models.common import init_params
 from repro.models.model import ShardCtx
 
-from repro.compat import make_mesh
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 for arch in ("gemma2-2b", "falcon-mamba-7b", "jamba-v0.1-52b", "minicpm3-4b"):
     cfg = dataclasses.replace(

@@ -129,3 +129,24 @@ def test_serve_driver_runs():
     assert out["requests"] == 4
     assert out["tokens"] == 16
     assert all(len(s) > 0 for s in out["samples"])
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR is left to JAX; otherwise a fixed dir in the checkout."""
+    from repro.launch import compile_cache as CC
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert CC.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            path = CC.enable_compile_cache()
+            assert path == str(CC.CACHE_DIR) == jax.config.jax_compilation_cache_dir
+            assert (CC.CACHE_DIR.parent / "pyproject.toml").is_file()
+            assert CC.enable_compile_cache() == path  # fixed across calls
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
