@@ -1,0 +1,8 @@
+"""Host time per decode step spent reading the step's tokens back: the
+summed ``repro/serve/readback`` host spans of the traced window over its
+``serve/decode_step`` spans (both the program's own, on the trace's clock)."""
+from lib import scopes as S
+
+
+def read(R):
+    return S.per_step_ms(R.trace, "repro/serve/readback") if R.trace else None

@@ -21,6 +21,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..telemetry.phases import NULL_CLOCK
 from .callstack import CallStackBuilder, FrameContext
 from .events import Frame
 from .stats import StatsTable
@@ -148,36 +149,42 @@ class OnNodeAD:
         self.n_anomalies_total = 0
         self.frames_seen = 0
 
-    def process_frame(self, frame: Frame) -> ADFrameResult:
-        records, ctx = self.builder.process(frame)
-        fids = records["fid"].astype(np.int64)
-        runtimes = records["runtime"].astype(np.float64)
+    def process_frame(self, frame: Frame, clock=NULL_CLOCK) -> ADFrameResult:
+        """Steps 1-4 for one frame, timed as the ``clock``'s phases
+        ``callstack``, ``ps_sync`` (the local fold that makes the frame's
+        delta, and the PS push/pull) and ``ad`` (labelling)."""
+        with clock.phase("callstack"):
+            records, ctx = self.builder.process(frame)
+            fids = records["fid"].astype(np.int64)
+            runtimes = records["runtime"].astype(np.float64)
 
-        # 1. fold into local stats; the delta is what travels to the PS.
-        if int(fids.max(initial=-1)) >= self.local.num_funcs:
-            self.local.grow(int(fids.max()) + 1)
-            self.global_view.grow(int(fids.max()) + 1)
-        delta = self.local.update_batch(fids, runtimes)
-        if isinstance(self.detector, HbosDetector):
-            self.detector.update(fids, runtimes)
+        with clock.phase("ps_sync"):
+            # 1. fold into local stats; the delta is what travels to the PS.
+            if int(fids.max(initial=-1)) >= self.local.num_funcs:
+                self.local.grow(int(fids.max()) + 1)
+                self.global_view.grow(int(fids.max()) + 1)
+            delta = self.local.update_batch(fids, runtimes)
+            if isinstance(self.detector, HbosDetector):
+                self.detector.update(fids, runtimes)
 
-        # 2. async PS exchange: push delta, pull global snapshot.
-        if self.ps_client is not None:
-            snapshot = self.ps_client.update_and_fetch(self.rank, frame.step, delta)
-            if snapshot is not None:
-                if snapshot.shape[0] > self.global_view.num_funcs:
-                    self.global_view.grow(snapshot.shape[0])
-                self.global_view.table = snapshot.copy()
-        else:
-            self.global_view.merge_array(delta)
+            # 2. async PS exchange: push delta, pull global snapshot.
+            if self.ps_client is not None:
+                snapshot = self.ps_client.update_and_fetch(self.rank, frame.step, delta)
+                if snapshot is not None:
+                    if snapshot.shape[0] > self.global_view.num_funcs:
+                        self.global_view.grow(snapshot.shape[0])
+                    self.global_view.table = snapshot.copy()
+            else:
+                self.global_view.merge_array(delta)
 
-        # 3. label against the freshest (global if available) statistics.
-        table = self.global_view if self.ps_client is not None else self.local
-        labels = self.detector.label(table, fids, runtimes)
-        records["label"] = labels
-        anomaly_idx = np.nonzero(labels == 1)[0]
-        self.n_anomalies_total += len(anomaly_idx)
-        self.frames_seen += 1
+        with clock.phase("ad"):
+            # 3. label against the freshest (global if available) statistics.
+            table = self.global_view if self.ps_client is not None else self.local
+            labels = self.detector.label(table, fids, runtimes)
+            records["label"] = labels
+            anomaly_idx = np.nonzero(labels == 1)[0]
+            self.n_anomalies_total += len(anomaly_idx)
+            self.frames_seen += 1
 
         return ADFrameResult(
             step=frame.step,

@@ -27,8 +27,19 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import StepOptions, build_decode_step, build_prefill_step, make_shard_ctx
 from repro.models import model as M
 from repro.models.common import init_params
+from repro.telemetry import registry as telemetry
+from repro.telemetry.phases import PhaseClock
 from repro.trace.monitor import ChimbukoMonitor
-from repro.trace.tracer import Tracer
+from repro.trace.tracer import Tracer, now_us
+
+# The loop's phases, each a ``repro_serve_phase_us`` series and a
+# ``repro/serve/<phase>`` profiler event: per wave ``prefill`` (its dispatch
+# and argmax), then per decode step ``wait`` (the device finishing the last
+# step and slot 0's slice), ``readback`` (one read per slot), ``dispatch``
+# (the step and its argmax) and ``monitor_step``; per wave ``drain`` and
+# ``ingest``; once ``finish``.
+SERVE_PHASES = ("prefill", "wait", "readback", "dispatch", "monitor_step", "drain",
+                "ingest", "finish")
 
 
 @dataclasses.dataclass
@@ -70,6 +81,13 @@ def serve(
     own_monitor = monitor is None
     monitor = monitor or ChimbukoMonitor(num_funcs=16, min_samples=8)
     tracer = Tracer(monitor.registry, rank=0)
+    clock = PhaseClock("serve", "repro_serve_phase_us",
+                       "serve() loop phase latency in microseconds.", "phase", SERVE_PHASES)
+    reg = telemetry.get_registry()
+    m_steps = reg.counter("repro_serve_decode_steps_total", "Decode steps serve() ran.")
+    m_tokens = reg.counter("repro_serve_tokens_total", "Tokens serve() read back.")
+    m_syncs = reg.counter("repro_serve_host_syncs_total",
+                          "Device-to-host reads serve()'s loop issued.")
 
     rng = np.random.default_rng(seed)
     pending = [
@@ -83,7 +101,7 @@ def serve(
     tokens_out = 0
     while pending:
         wave, pending = pending[:batch], pending[batch:]
-        with tracer.span("serve/prefill"):
+        with tracer.span("serve/prefill"), clock.phase("prefill"):
             prompts = np.stack([r.prompt for r in wave])
             if len(wave) < batch:  # pad the wave to the compiled batch
                 pad = np.tile(prompts[-1:], (batch - len(wave), 1))
@@ -91,18 +109,33 @@ def serve(
             logits, cache = prefill_fn(params, {"tokens": jnp.asarray(prompts)})
             next_tok = jnp.argmax(logits[:, -1], axis=-1)
         for t in range(max_new):
-            t0 = time.perf_counter()
-            with tracer.span("serve/decode_step"):
-                for i, r in enumerate(wave):
-                    r.out.append(int(next_tok[i]))
-                tokens_out += len(wave)
-                logits, cache = decode_fn(params, cache, next_tok[:, None].astype(jnp.int32))
-                next_tok = jnp.argmax(logits[:, 0], axis=-1)
-            monitor.record_step_times(step, {0: time.perf_counter() - t0})
+            with tracer.span("serve/decode_step") as t_entry_us:
+                # Slot 0's slice is dispatched before the wait, as its read
+                # always was, so that it runs as soon as the step is done.
+                with clock.phase("wait"):
+                    head = jax.block_until_ready(next_tok[0])
+                with clock.phase("readback"):
+                    for i, r in enumerate(wave):
+                        r.out.append(int(head if i == 0 else next_tok[i]))
+                    m_syncs.inc(len(wave))
+                with clock.phase("dispatch"):
+                    logits, cache = decode_fn(params, cache, next_tok[:, None].astype(jnp.int32))
+                    next_tok = jnp.argmax(logits[:, 0], axis=-1)
+                # The step time the straggler detector judges: this span so far.
+                step_s = (now_us() - t_entry_us) / 1e6
+                with clock.phase("monitor_step"):
+                    monitor.record_step_times(step, {0: step_s})
+            tokens_out += len(wave)
+            m_steps.inc()
+            m_tokens.inc(len(wave))
             step += 1
         finished.extend(wave)
-        monitor.ingest(tracer.drain(step))
-    jax.block_until_ready(next_tok)
+        with clock.phase("drain"):
+            frame = tracer.drain(step)
+        with clock.phase("ingest"):
+            monitor.ingest(frame)
+    with clock.phase("finish"):
+        jax.block_until_ready(next_tok)
     dt = time.perf_counter() - t_start
     out = {
         "requests": len(finished),

@@ -132,14 +132,16 @@ def build_train_step(
 
 def build_prefill_step(cfg, ctx, opts, max_seq=None) -> Callable:
     def prefill_step(params, batch):
-        return M.prefill(cfg, params, batch, ctx, max_seq=max_seq)
+        with jax.named_scope("prefill"):  # the program's ops, by a stable name
+            return M.prefill(cfg, params, batch, ctx, max_seq=max_seq)
 
     return prefill_step
 
 
 def build_decode_step(cfg, ctx, opts) -> Callable:
     def decode_step(params, cache, tokens):
-        return M.decode_step(cfg, params, cache, tokens, ctx)
+        with jax.named_scope("decode"):
+            return M.decode_step(cfg, params, cache, tokens, ctx)
 
     return decode_step
 

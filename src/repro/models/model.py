@@ -255,30 +255,31 @@ def _mla_seq(cfg, spec, p, h, cos, sin, ctx, with_cache=False):
 
 
 def _mlp_apply(cfg, spec, p, h, ctx: ShardCtx):
-    if spec.mlp == MOE:
-        ep = ctx.ep_info(cfg)
-        if ep is not None:
-            fn = shard_map(
-                lambda pr, xr: moe_block(pr, xr, cfg, ep),
-                mesh=ctx.mesh,
-                in_specs=(
-                    {
-                        "router": P(),
-                        "moe_gate": P(ctx.model_axis),
-                        "moe_up": P(ctx.model_axis),
-                        "moe_down": P(ctx.model_axis),
-                    },
-                    P(*ctx.token_pspec, None, None),
-                ),
-                out_specs=P(*ctx.token_pspec, None, None),
+    with jax.named_scope("moe" if spec.mlp == MOE else "mlp"):
+        if spec.mlp == MOE:
+            ep = ctx.ep_info(cfg)
+            if ep is not None:
+                fn = shard_map(
+                    lambda pr, xr: moe_block(pr, xr, cfg, ep),
+                    mesh=ctx.mesh,
+                    in_specs=(
+                        {
+                            "router": P(),
+                            "moe_gate": P(ctx.model_axis),
+                            "moe_up": P(ctx.model_axis),
+                            "moe_down": P(ctx.model_axis),
+                        },
+                        P(*ctx.token_pspec, None, None),
+                    ),
+                    out_specs=P(*ctx.token_pspec, None, None),
+                )
+                sub = {k2: p[k2] for k2 in ("router", "moe_gate", "moe_up", "moe_down")}
+                return fn(sub, h)
+            return moe_block(
+                {k2: p[k2] for k2 in ("router", "moe_gate", "moe_up", "moe_down")},
+                h, cfg, None,
             )
-            sub = {k2: p[k2] for k2 in ("router", "moe_gate", "moe_up", "moe_down")}
-            return fn(sub, h)
-        return moe_block(
-            {k2: p[k2] for k2 in ("router", "moe_gate", "moe_up", "moe_down")},
-            h, cfg, None,
-        )
-    return L.mlp(p, h, cfg.activation)
+        return L.mlp(p, h, cfg.activation)
 
 
 def apply_block(cfg, spec: LayerSpec, p, x, cos, sin, ctx: ShardCtx) -> jnp.ndarray:
@@ -423,9 +424,10 @@ def _attn_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx):
         q, k = L.apply_rope(q, cos, sin), L.apply_rope(k, cos, sin)
     Sc = cache["k"].shape[1]  # cache slice inside scan: (B, Sc, KV, hd)
     slot = pos % Sc  # ring for SWA; plain index otherwise (pos < Sc)
-    ck = jax.lax.dynamic_update_index_in_dim(cache["k"], k[:, 0], slot, axis=1)
-    cv = jax.lax.dynamic_update_index_in_dim(cache["v"], v[:, 0], slot, axis=1)
-    kpos = jax.lax.dynamic_update_index_in_dim(cache["kpos"], pos, slot, axis=0)
+    with jax.named_scope("kv_update"):
+        ck = jax.lax.dynamic_update_index_in_dim(cache["k"], k[:, 0], slot, axis=1)
+        cv = jax.lax.dynamic_update_index_in_dim(cache["v"], v[:, 0], slot, axis=1)
+        kpos = jax.lax.dynamic_update_index_in_dim(cache["kpos"], pos, slot, axis=0)
     window = cfg.window if spec.mixer == SWA else 0
     acc, m, l = L.attention_partial(
         q, ck, cv, causal=True, window=window, cap=cfg.attn_softcap,
@@ -582,10 +584,11 @@ def _mla_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx):
         cr, sr = cos[..., : rope // 2], sin[..., : rope // 2]
         q_rope = L.apply_rope(q_rope, cr, sr)
         krope_new = L.apply_rope(krope_new, cr, sr)
-    ckv = jax.lax.dynamic_update_index_in_dim(cache["ckv"], ckv_new[:, 0], pos, axis=1)
-    krope = jax.lax.dynamic_update_index_in_dim(
-        cache["krope"], krope_new[:, 0, 0], pos, axis=1
-    )
+    with jax.named_scope("kv_update"):
+        ckv = jax.lax.dynamic_update_index_in_dim(cache["ckv"], ckv_new[:, 0], pos, axis=1)
+        krope = jax.lax.dynamic_update_index_in_dim(
+            cache["krope"], krope_new[:, 0, 0], pos, axis=1
+        )
     # absorb W_uk into q:  q_eff (B,1,H,kvr)
     wuk = p["wuk"].reshape(kvr, H, nope)
     q_eff = jnp.einsum("bqhn,khn->bqhk", q_nope, wuk)
@@ -606,14 +609,15 @@ def _mla_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx):
 
 
 def decode_block(cfg, spec, p, x, cache, pos, cos, sin, ctx):
-    p = _cast_block_params(p, cfg.compute_dtype)
+    """One layer of a decode step, on weights already cast to the compute dtype."""
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-    if spec.mixer == MAMBA:
-        h, new_cache = mamba_decode(p, h, cache, cfg)
-    elif spec.mixer == MLA:
-        h, new_cache = _mla_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx)
-    else:
-        h, new_cache = _attn_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx)
+    with jax.named_scope("attention"):
+        if spec.mixer == MAMBA:
+            h, new_cache = mamba_decode(p, h, cache, cfg)
+        elif spec.mixer == MLA:
+            h, new_cache = _mla_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx)
+        else:
+            h, new_cache = _attn_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx)
     if cfg.sandwich_norm:
         h = L.rms_norm(h, p["post_ln1"], cfg.norm_eps)
     x = x + h
@@ -629,15 +633,25 @@ def decode_block(cfg, spec, p, x, cache, pos, cos, sin, ctx):
 def decode_step(
     cfg: ModelConfig, params, cache, tokens: jnp.ndarray, ctx: ShardCtx = ShardCtx()
 ):
-    """One decode step: tokens (B, 1) -> (logits (B, 1, V), new cache)."""
+    """One decode step: tokens (B, 1) -> (logits (B, 1, V), new cache).
+
+    Its ops carry the name scopes ``embed``, ``cast_params``, ``attention``
+    (with ``kv_update``), ``moe`` and ``lm_head``: stable names for a
+    profiler trace's per-op reduction."""
     pos = cache["pos"]
-    x = embed_tokens(cfg, params, {"tokens": tokens})
+    with jax.named_scope("embed"):
+        x = embed_tokens(cfg, params, {"tokens": tokens})
     B = x.shape[0]
     positions = (
         jnp.broadcast_to(pos, (3, B, 1)) if cfg.pos == "mrope"
         else jnp.full((B, 1), pos)
     )
     cos, sin = _rope_cos_sin(cfg, positions, cfg.qk_dim)
+    # The compute-dtype copies of every layer's weights, made before the
+    # layer scan: XLA hoists a cast out of the scan, and the cast it
+    # hoists keeps no name scope.
+    with jax.named_scope("cast_params"):
+        layers = [_cast_block_params(p, cfg.compute_dtype) for p in params["layers"]]
 
     def body(xc, slices):
         period_params, period_cache = slices
@@ -650,9 +664,10 @@ def decode_step(
         return xc, new_caches
 
     x, new_layer_cache = jax.lax.scan(
-        body, x, (params["layers"], cache["layers"]), unroll=ctx.scan_unroll
+        body, x, (layers, cache["layers"]), unroll=ctx.scan_unroll
     )
-    logits = unembed(cfg, params, x)
+    with jax.named_scope("lm_head"):
+        logits = unembed(cfg, params, x)
     return logits, {"pos": pos + 1, "layers": new_layer_cache}
 
 
@@ -703,8 +718,10 @@ def prefill(
     cfg: ModelConfig, params, batch, ctx: ShardCtx = ShardCtx(),
     max_seq: Optional[int] = None,
 ):
-    """Sequence pass returning (last-position logits, populated cache)."""
-    x = embed_tokens(cfg, params, batch)
+    """Sequence pass returning (last-position logits, populated cache), its
+    ops scoped like ``decode_step``'s."""
+    with jax.named_scope("embed"):
+        x = embed_tokens(cfg, params, batch)
     B, S, _ = x.shape
     positions = _positions(cfg, batch, B, S)
     cos, sin = _rope_cos_sin(cfg, positions, cfg.qk_dim)
@@ -713,25 +730,28 @@ def prefill(
     def body(xc, period_params):
         caches = []
         for i, spec in enumerate(cfg.layout):
-            p = _cast_block_params(period_params[i], cfg.compute_dtype)
+            with jax.named_scope("cast_params"):
+                p = _cast_block_params(period_params[i], cfg.compute_dtype)
             h = L.rms_norm(xc, p["ln1"], cfg.norm_eps)
-            if spec.mixer == MAMBA:
-                # full-sequence mixer; rebuild final state for the cache
-                hh = mamba_sequence(p, h, cfg, chunk=(h.shape[1] if ctx.unroll else 128))
-                cch = _mamba_prefill_state(cfg, p, h)
-                h = hh
-            elif spec.mixer == MLA:
-                h, (ckv, krope) = _mla_seq(cfg, spec, p, h, cos, sin, ctx, with_cache=True)
-                cch = {"ckv": ckv, "krope": krope}
-            else:
-                h, (k, v) = _attn_seq_with_cache(cfg, spec, p, h, cos, sin, ctx)
-                if spec.mixer == SWA:
-                    w = min(cfg.window, S)
-                    k, v = k[:, -w:], v[:, -w:]
-                    kpos = jnp.arange(S - w, S, dtype=jnp.int32)
+            with jax.named_scope("attention"):
+                if spec.mixer == MAMBA:
+                    # full-sequence mixer; rebuild final state for the cache
+                    hh = mamba_sequence(p, h, cfg, chunk=(h.shape[1] if ctx.unroll else 128))
+                    cch = _mamba_prefill_state(cfg, p, h)
+                    h = hh
+                elif spec.mixer == MLA:
+                    h, (ckv, krope) = _mla_seq(cfg, spec, p, h, cos, sin, ctx, with_cache=True)
+                    cch = {"ckv": ckv, "krope": krope}
                 else:
-                    kpos = jnp.arange(S, dtype=jnp.int32)
-                cch = {"k": k, "v": v, "kpos": kpos}
+                    h, (k, v) = _attn_seq_with_cache(cfg, spec, p, h, cos, sin, ctx)
+                    with jax.named_scope("kv_update"):
+                        if spec.mixer == SWA:
+                            w = min(cfg.window, S)
+                            k, v = k[:, -w:], v[:, -w:]
+                            kpos = jnp.arange(S - w, S, dtype=jnp.int32)
+                        else:
+                            kpos = jnp.arange(S, dtype=jnp.int32)
+                        cch = {"k": k, "v": v, "kpos": kpos}
             if cfg.sandwich_norm:
                 h = L.rms_norm(h, p["post_ln1"], cfg.norm_eps)
             xc = xc + h
@@ -748,9 +768,11 @@ def prefill(
     if ctx.remat == "block":
         body = jax.checkpoint(body)
     x, layer_caches = jax.lax.scan(body, x, params["layers"], unroll=ctx.scan_unroll)
-    logits = unembed(cfg, params, x[:, -1:])
+    with jax.named_scope("lm_head"):
+        logits = unembed(cfg, params, x[:, -1:])
     if max_seq is not None and max_seq != S:
-        layer_caches = _expand_prefill_cache(cfg, layer_caches, S, max_seq)
+        with jax.named_scope("kv_update"):
+            layer_caches = _expand_prefill_cache(cfg, layer_caches, S, max_seq)
     return logits, {"pos": jnp.asarray(S, jnp.int32), "layers": layer_caches}
 
 

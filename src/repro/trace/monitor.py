@@ -10,7 +10,6 @@ rebalance) — the fault-tolerance hook the framework exposes at scale.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import defaultdict
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -24,42 +23,13 @@ from repro.core.reduction import Reducer, merge_stats
 from repro.core.stats import RunningStats
 from repro.telemetry import registry as telemetry
 from repro.telemetry import spans
+from repro.telemetry.phases import PhaseClock
 from repro.telemetry.ring import get_ring, prefer_recording
 from repro.telemetry.selftrace import SELF_TRACE_PID, get_self_tracer
 
-_INGEST_STAGES = ("ad", "reduce", "ps", "prov", "write", "publish")
-
-
-class _StageTimer:
-    """Per-frame stage clock: marks observe the stage histogram and, when
-    self-tracing, record the stage as a span."""
-
-    __slots__ = ("_hists", "_tracer", "_last")
-
-    def __init__(self, hists, tracer):
-        self._hists = hists
-        self._tracer = tracer
-        self._last = time.perf_counter_ns()
-
-    def mark(self, stage: str) -> None:
-        now = time.perf_counter_ns()
-        dur_ns = now - self._last
-        self._hists[stage].observe(dur_ns // 1000)
-        if self._tracer is not None:
-            self._tracer.record(
-                f"ingest:{stage}", self._last // 1000, dur_ns // 1000
-            )
-        self._last = now
-
-
-class _NullTimer:
-    __slots__ = ()
-
-    def mark(self, stage: str) -> None:
-        pass
-
-
-_NULL_TIMER = _NullTimer()
+# Per-frame ingest stages, in the order they run; the first three are
+# OnNodeAD.process_frame's.
+_INGEST_STAGES = ("callstack", "ps_sync", "ad", "reduce", "ps", "prov", "write", "publish")
 
 
 @dataclasses.dataclass
@@ -109,12 +79,6 @@ class ChimbukoMonitor:
         # opt-in self-trace (REPRO_SELF_TRACE=1 or self_trace=True) that
         # drains the analyzer's own spans into the live trace export as a
         # dedicated process group.
-        _stage_family = telemetry.get_registry().histogram(
-            "repro_frame_stage_us",
-            "Per-frame ingest pipeline stage latency in microseconds.",
-            ["stage"],
-        )
-        self._m_stage = {s: _stage_family.labels(stage=s) for s in _INGEST_STAGES}
         self._m_frames = telemetry.get_registry().counter(
             "repro_frames_ingested_total",
             "Frames run through the full in-situ ingest path.",
@@ -122,6 +86,11 @@ class ChimbukoMonitor:
         self._selftrace = get_self_tracer()
         if self_trace is not None:
             self._selftrace.set_enabled(bool(self_trace))
+        self._clock = PhaseClock(
+            "ingest", "repro_frame_stage_us",
+            "Per-frame ingest pipeline stage latency in microseconds.",
+            "stage", _INGEST_STAGES, selftrace=self._selftrace,
+        )
         self._selftrace_proc_named = False
         # Distributed request tracing (repro.telemetry.spans): every ingest
         # runs under a deterministic per-frame trace root; anomalous frames
@@ -272,67 +241,60 @@ class ChimbukoMonitor:
                 )
 
     def _ingest_frame(self, frame: Frame) -> ADFrameResult:
-        if telemetry.ENABLED:
-            timer = _StageTimer(
-                self._m_stage,
-                self._selftrace if self._selftrace.enabled else None,
-            )
-        else:
-            timer = _NULL_TIMER
-        res = self._ad(frame.rank).process_frame(frame)
-        if res.n_anomalies and spans.ENABLED:
-            # Tail sampling: the anomaly verdict upgrades the frame's
-            # sampled bit before the provenance writes ship, so the whole
-            # anomaly path (client + server + ingest spans) is kept.  PS
-            # pushes travel inside process_frame, before the verdict — they
-            # follow the 1/N policy.
-            spans.mark_sampled()
-        timer.mark("ad")
-        kept_idx = self.reducers[frame.rank].reduce(res)
-        kept = res.records[kept_idx]
-        self.kept[(frame.rank, frame.step)] = kept
-        timer.mark("reduce")
-        self.ps.report_anomalies(frame.rank, frame.step, res.n_anomalies)
-        timer.mark("ps")
-        anom: List[Tuple[int, int, int]] = []
-        if res.n_anomalies:
-            self.provdb.ingest(res, frame.comm_events)
-            # Link each anomalous kept record to the provenance doc it just
-            # produced (anomalies are always kept, so the searchsorted map
-            # is total).  (kept_idx, global seq, severity) triples feed the
-            # trace exporter's instant events.
-            kpos = np.searchsorted(kept_idx, res.anomaly_idx)
-            anom = [
-                (int(k), int(seq), int(sev))
-                for k, (seq, sev) in zip(kpos, self.provdb.last_ingest)
-            ]
-        timer.mark("prov")
-        if anom and spans.ENABLED:
-            max_sev = max(sev for _k, _s, sev in anom)
-            if max_sev >= self._span_dump_severity:
-                get_ring().dump(
-                    f"anomaly:sev{max_sev}:r{frame.rank}s{frame.step}"
+        clock = self._clock
+        res = self._ad(frame.rank).process_frame(frame, clock)
+        with clock.phase("reduce"):
+            if res.n_anomalies and spans.ENABLED:
+                # Tail sampling: the anomaly verdict upgrades the frame's
+                # sampled bit before the provenance writes ship, so the
+                # whole anomaly path (client + server + ingest spans) is
+                # kept.  PS pushes travel inside process_frame, before the
+                # verdict — they follow the 1/N policy.
+                spans.mark_sampled()
+            kept_idx = self.reducers[frame.rank].reduce(res)
+            kept = res.records[kept_idx]
+            self.kept[(frame.rank, frame.step)] = kept
+        with clock.phase("ps"):
+            self.ps.report_anomalies(frame.rank, frame.step, res.n_anomalies)
+        with clock.phase("prov"):
+            anom: List[Tuple[int, int, int]] = []
+            if res.n_anomalies:
+                self.provdb.ingest(res, frame.comm_events)
+                # Link each anomalous kept record to the provenance doc it
+                # just produced (anomalies are always kept, so the
+                # searchsorted map is total).  (kept_idx, global seq,
+                # severity) triples feed the trace exporter's instant events.
+                kpos = np.searchsorted(kept_idx, res.anomaly_idx)
+                anom = [
+                    (int(k), int(seq), int(sev))
+                    for k, (seq, sev) in zip(kpos, self.provdb.last_ingest)
+                ]
+        with clock.phase("write"):
+            if anom and spans.ENABLED:
+                max_sev = max(sev for _k, _s, sev in anom)
+                if max_sev >= self._span_dump_severity:
+                    get_ring().dump(
+                        f"anomaly:sev{max_sev}:r{frame.rank}s{frame.step}"
+                    )
+            ts = int(res.records["exit"].max()) if len(res.records) else None
+            key = (frame.rank, frame.step)
+            self.frame_meta[key] = (ts, len(res.records), res.n_anomalies)
+            self.anom_meta[key] = anom
+            for writer in (self._stream_writer, self._trace_writer):
+                if writer is not None:
+                    writer.add_frame(
+                        frame.rank, frame.step, kept, self.registry.names,
+                        anomalies=anom, n_records=len(res.records),
+                        n_anomalies=res.n_anomalies, ts=ts,
+                    )
+        with clock.phase("publish"):
+            self.frames_ingested += 1
+            self._m_frames.inc()
+            if self.viz_gateway is not None:
+                self.viz_gateway.publish_frame(
+                    frame.rank, frame.step, res.n_anomalies,
+                    severity=max((sev for _k, _s, sev in anom), default=0),
                 )
-        ts = int(res.records["exit"].max()) if len(res.records) else None
-        key = (frame.rank, frame.step)
-        self.frame_meta[key] = (ts, len(res.records), res.n_anomalies)
-        self.anom_meta[key] = anom
-        for writer in (self._stream_writer, self._trace_writer):
-            if writer is not None:
-                writer.add_frame(
-                    frame.rank, frame.step, kept, self.registry.names,
-                    anomalies=anom, n_records=len(res.records),
-                    n_anomalies=res.n_anomalies, ts=ts,
-                )
-        timer.mark("write")
-        self.frames_ingested += 1
-        self._m_frames.inc()
-        if self.viz_gateway is not None:
-            self.viz_gateway.publish_frame(
-                frame.rank, frame.step, res.n_anomalies,
-                severity=max((sev for _k, _s, sev in anom), default=0),
-            )
-        timer.mark("publish")
         if self._trace_writer is not None and self._selftrace.enabled:
             self._drain_selftrace()
         return res

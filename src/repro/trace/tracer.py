@@ -29,6 +29,7 @@ from repro.core.events import (
     empty_comm_events,
     empty_func_events,
 )
+from repro.telemetry.phases import annotation
 
 
 def now_us() -> int:
@@ -63,19 +64,25 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, filterable: bool = False):
+        """ENTRY/EXIT events of ``name`` around the block, which also runs
+        inside a profiler ``TraceAnnotation`` of the same name, so that the
+        call sits on the device trace's timeline.  Yields the entry stamp
+        (``now_us``)."""
         fid = self.register(name, filterable)
         if self.filtered and fid in self._filterable:
             self.n_dropped += 2
-            yield
+            yield now_us()
             return
         tid = threading.get_ident() % 2**31
-        with self._lock:
-            self._func_rows.append((tid, fid, int(ENTRY), now_us()))
-        try:
-            yield
-        finally:
+        with annotation(name):
+            t0 = now_us()
             with self._lock:
-                self._func_rows.append((tid, fid, int(EXIT), now_us()))
+                self._func_rows.append((tid, fid, int(ENTRY), t0))
+            try:
+                yield t0
+            finally:
+                with self._lock:
+                    self._func_rows.append((tid, fid, int(EXIT), now_us()))
 
     def fn(self, name: str, filterable: bool = False):
         """Decorator form of span()."""

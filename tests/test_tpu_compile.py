@@ -1,4 +1,5 @@
-"""AOT compiles of the moments kernel for a described TPU v5e chip.
+"""AOT compiles for a described TPU v5e chip: the moments kernel, and the
+served decode step's op metadata.
 
 Interpret mode (tests/test_kernels.py) cannot see Mosaic's layout and VMEM
 rules; the TPU compiler can, without a chip attached.  The topology is
@@ -7,6 +8,7 @@ collects the same tests and only the worker running this file loads the
 TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,3 +56,35 @@ def test_moments_kernel_compiles_for_v5e(one_chip, num_funcs, num_events):
     delta, labels = compiled.out_info
     assert delta.shape == (num_funcs, 5) and delta.dtype == jnp.float32
     assert labels.shape == (num_events,) and labels.dtype == jnp.int8
+
+
+def test_decode_step_expert_casts_keep_their_scope_for_v5e(one_chip):
+    """At published widths, each float32->bfloat16 convert of the stacked
+    expert weights (the decode step's largest device cost) keeps the
+    ``cast_params`` name scope through XLA's passes: ``decode_cast_share``
+    reads it from the ops' metadata in a profiler trace."""
+    from repro import configs
+    from repro.launch.steps import (StepOptions, build_decode_step, build_prefill_step,
+                                    make_shard_ctx)
+    from repro.models.common import init_params
+
+    cfg = configs.get_config("granite_moe_1b_a400m")
+    batch, prompt = 8, 1024
+    opts = StepOptions()
+    ctx = make_shard_ctx(cfg, None, batch, opts)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: init_params(cfg, jax.random.key(0))))
+    prompts = on_chip({"tokens": jax.ShapeDtypeStruct((batch, prompt), jnp.int32)})
+    prefill = build_prefill_step(cfg, ctx, opts, max_seq=prompt + 128)
+    cache = on_chip(jax.eval_shape(prefill, params, prompts)[1])
+    tokens = on_chip(jax.ShapeDtypeStruct((batch, 1), jnp.int32))
+    text = jax.jit(build_decode_step(cfg, ctx, opts), donate_argnums=(1,)).lower(
+        params, cache, tokens).compile().as_text()
+    expert = re.compile(rf"= bf16\[{cfg.n_layers},{cfg.moe_experts},\d+,\d+\]\S* convert\(")
+    casts = [line for line in text.splitlines() if expert.search(line)]
+    assert len(casts) == 3  # gate, up, down
+    assert all('op_name="jit(decode_step)/decode/cast_params/' in line for line in casts)
