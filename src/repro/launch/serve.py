@@ -66,15 +66,26 @@ def serve(
     opts = StepOptions()
     ctx = make_shard_ctx(cfg, None, batch, opts)
     max_seq = prompt_len + max_new
-    params = init_params(cfg, jax.random.key(seed))
+    # The masters, kept resident as a deployment keeps them.  The copy waits
+    # for them: dispatched at once, its buffers would be allocated while the
+    # initialisation's temporaries are still held, and raise the peak.
+    params = jax.block_until_ready(init_params(cfg, jax.random.key(seed)))
+    # The weights never change here, so the steps run on their compute-dtype
+    # copy, made once, and cast nothing themselves.
+    cparams = M.compute_params(cfg, params)
+    copied = sum(c.nbytes for c, p in zip(jax.tree.leaves(cparams), jax.tree.leaves(params))
+                 if c.dtype != p.dtype)
+    reg = telemetry.get_registry()
+    reg.gauge("repro_serve_compute_param_bytes",
+              "Bytes of the compute-dtype weight copy serve() made.").set(copied)
     # Both steps compile before the clock starts: tok_per_s excludes it.
     t_compile = time.perf_counter()
     prefill = build_prefill_step(cfg, ctx, opts, max_seq=max_seq)
     prompt_spec = {"tokens": jax.ShapeDtypeStruct((batch, prompt_len), jnp.int32)}
-    prefill_fn = jax.jit(prefill).lower(params, prompt_spec).compile()
-    cache_spec = jax.eval_shape(prefill, params, prompt_spec)[1]
+    prefill_fn = jax.jit(prefill).lower(cparams, prompt_spec).compile()
+    cache_spec = jax.eval_shape(prefill, cparams, prompt_spec)[1]
     decode_fn = jax.jit(build_decode_step(cfg, ctx, opts), donate_argnums=(1,)).lower(
-        params, cache_spec, jax.ShapeDtypeStruct((batch, 1), jnp.int32)
+        cparams, cache_spec, jax.ShapeDtypeStruct((batch, 1), jnp.int32)
     ).compile()
     compile_s = time.perf_counter() - t_compile
 
@@ -83,7 +94,6 @@ def serve(
     tracer = Tracer(monitor.registry, rank=0)
     clock = PhaseClock("serve", "repro_serve_phase_us",
                        "serve() loop phase latency in microseconds.", "phase", SERVE_PHASES)
-    reg = telemetry.get_registry()
     m_steps = reg.counter("repro_serve_decode_steps_total", "Decode steps serve() ran.")
     m_tokens = reg.counter("repro_serve_tokens_total", "Tokens serve() read back.")
     m_syncs = reg.counter("repro_serve_host_syncs_total",
@@ -106,7 +116,7 @@ def serve(
             if len(wave) < batch:  # pad the wave to the compiled batch
                 pad = np.tile(prompts[-1:], (batch - len(wave), 1))
                 prompts = np.concatenate([prompts, pad])
-            logits, cache = prefill_fn(params, {"tokens": jnp.asarray(prompts)})
+            logits, cache = prefill_fn(cparams, {"tokens": jnp.asarray(prompts)})
             next_tok = jnp.argmax(logits[:, -1], axis=-1)
         for t in range(max_new):
             with tracer.span("serve/decode_step") as t_entry_us:
@@ -119,7 +129,7 @@ def serve(
                         r.out.append(int(head if i == 0 else next_tok[i]))
                     m_syncs.inc(len(wave))
                 with clock.phase("dispatch"):
-                    logits, cache = decode_fn(params, cache, next_tok[:, None].astype(jnp.int32))
+                    logits, cache = decode_fn(cparams, cache, next_tok[:, None].astype(jnp.int32))
                     next_tok = jnp.argmax(logits[:, 0], axis=-1)
                 # The step time the straggler detector judges: this span so far.
                 step_s = (now_us() - t_entry_us) / 1e6

@@ -13,6 +13,7 @@ O(1) state.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -122,6 +123,21 @@ _F32_KEYS = frozenset({"A_log"})  # kept f32: used only inside f32 math
 def _cast_block_params(p: Dict[str, jnp.ndarray], dtype) -> Dict[str, jnp.ndarray]:
     """bf16 compute casts of the fp32 master weights (mixed precision)."""
     return {k: (v if k in _F32_KEYS else v.astype(dtype)) for k, v in p.items()}
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def compute_params(cfg: ModelConfig, params):
+    """The compute-dtype copy of what ``prefill`` and ``decode_step`` cast:
+    the layers as ``_cast_block_params`` casts them, and the embedding and
+    unembedding, which are cast right after every read.  Every other leaf
+    is kept as it is.  On this copy the steps' casts are no-ops, so a
+    server that never changes its weights casts them once, not per step."""
+    out = dict(params)
+    out["layers"] = [_cast_block_params(p, cfg.compute_dtype) for p in params["layers"]]
+    for k in ("embed", "unembed"):
+        if k in params:
+            out[k] = params[k].astype(cfg.compute_dtype)
+    return out
 
 
 def _attention_seq_parallel(
@@ -649,7 +665,8 @@ def decode_step(
     cos, sin = _rope_cos_sin(cfg, positions, cfg.qk_dim)
     # The compute-dtype copies of every layer's weights, made before the
     # layer scan: XLA hoists a cast out of the scan, and the cast it
-    # hoists keeps no name scope.
+    # hoists keeps no name scope.  On ``compute_params``' copy there is
+    # nothing to cast.
     with jax.named_scope("cast_params"):
         layers = [_cast_block_params(p, cfg.compute_dtype) for p in params["layers"]]
 

@@ -71,6 +71,34 @@ def test_decode_matches_forward(arch):
     )
 
 
+@pytest.mark.parametrize("arch", ["granite_moe_1b_a400m", "falcon_mamba_7b"])
+def test_compute_params_serve_bitwise(arch):
+    """prefill and decode_step, compiled on compute_params' copy, give the
+    same logits, caches and greedy tokens, bit for bit, as on the float32
+    masters: every matmul reads the same compute-dtype weights."""
+    cfg = configs.smoke(arch)
+    B, S, steps = 2, 8, 3
+    params = init_params(cfg, jax.random.key(4))
+    cparams = M.compute_params(cfg, params)
+    assert cparams["embed"].dtype == cfg.compute_dtype
+    assert cparams["final_ln"].dtype == params["final_ln"].dtype
+    tokens = synthetic_batch(cfg, B, S, seed=5)["tokens"]
+    prefill = jax.jit(lambda p, t: M.prefill(cfg, p, {"tokens": t}, max_seq=S + steps))
+    decode = jax.jit(lambda p, c, t: M.decode_step(cfg, p, c, t))
+
+    def run(p):
+        logits, cache = prefill(p, tokens)
+        outs = [(logits, cache)]
+        for _ in range(steps):
+            tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
+            logits, cache = decode(p, cache, tok)
+            outs.append((tok, logits, cache))
+        return outs
+
+    for got, want in zip(run(cparams), run(params)):
+        jax.tree.map(np.testing.assert_array_equal, got, want)
+
+
 def test_swa_ring_buffer_consistency():
     """Decode past the window: ring buffer must equal windowed reference."""
     cfg = configs.smoke("h2o-danube-3-4b")

@@ -94,8 +94,9 @@ def test_serve_phases_nest_in_tracer_spans_on_the_profiler_clock(served, outer, 
         assert all(a[1] <= b[0] for a, b in zip(ends, ends[1:])), (outer, i)
 
 
-@pytest.mark.parametrize("program", ["prefill", "decode"])
-def test_programs_carry_stable_name_scopes(program):
+def _lowered_names(program, params_of):
+    """The op names of ``program`` lowered at smoke widths on
+    ``params_of(cfg, params)``."""
     import jax
     import jax.numpy as jnp
 
@@ -107,7 +108,7 @@ def test_programs_carry_stable_name_scopes(program):
     cfg = configs.smoke("granite_moe_1b_a400m")
     opts = StepOptions()
     ctx = make_shard_ctx(cfg, None, BATCH, opts)
-    params = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    params = jax.eval_shape(lambda: params_of(cfg, init_params(cfg, jax.random.key(0))))
     prefill = build_prefill_step(cfg, ctx, opts, max_seq=PROMPT + NEW)
     prompts = {"tokens": jax.ShapeDtypeStruct((BATCH, PROMPT), jnp.int32)}
     if program == "prefill":
@@ -116,12 +117,61 @@ def test_programs_carry_stable_name_scopes(program):
         cache = jax.eval_shape(prefill, params, prompts)[1]
         lowered = jax.jit(build_decode_step(cfg, ctx, opts)).lower(
             params, cache, jax.ShapeDtypeStruct((BATCH, 1), jnp.int32))
-    op_names = re.findall(r'op_name="([^"]+)"', lowered.as_text(dialect="hlo", debug_info=True))
+    return re.findall(r'op_name="([^"]+)"', lowered.as_text(dialect="hlo", debug_info=True))
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_programs_carry_stable_name_scopes(program):
+    op_names = _lowered_names(program, lambda cfg, p: p)
     scopes = {part for name in op_names for part in name.split("/")}
     for scope in (program, "embed", "cast_params", "attention", "kv_update", "moe", "lm_head"):
         assert scope in scopes, scope
     # the weight cast is the convert under cast_params
     assert any(name.endswith("cast_params/convert_element_type") for name in op_names)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_programs_on_compute_params_cast_nothing(program):
+    """On compute_params' copy the steps' weight casts are no-ops: no
+    convert under ``cast_params`` (on the float32 masters there is one,
+    as the test above asserts)."""
+    from repro.models.model import compute_params
+
+    names = _lowered_names(program, compute_params)
+    assert names and not any("cast_params/convert_element_type" in n for n in names)
+
+
+def test_serve_compute_param_bytes(served):
+    """The gauge holds the bytes of serve()'s compute-dtype copy: every
+    layer weight and the embedding, in bfloat16."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro import configs
+    from repro.models.common import init_params
+
+    cfg = configs.smoke("granite_moe_1b_a400m")
+    shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    cast = jax.tree.leaves(shapes["layers"]) + [shapes["embed"]]
+    want = sum(s.size for s in cast) * jnp.dtype(cfg.compute_dtype).itemsize
+    _out, _before, after, _events = served
+    assert _series(after, "repro_serve_compute_param_bytes")[()] == want
+
+
+def test_serve_compute_param_bytes_zero_when_params_in_compute_dtype(monkeypatch):
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from repro import configs
+    from repro.launch.serve import serve
+    from repro.telemetry.registry import get_registry
+
+    smoke = configs.smoke
+    monkeypatch.setattr(configs, "smoke",
+                        lambda arch: dataclasses.replace(smoke(arch), compute_dtype=jnp.float32))
+    serve(smoke=True, n_requests=1, batch=1, prompt_len=4, max_new=1)
+    assert _series(get_registry().snapshot(), "repro_serve_compute_param_bytes")[()] == 0
 
 
 @pytest.fixture(scope="module")

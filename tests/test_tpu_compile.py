@@ -58,11 +58,9 @@ def test_moments_kernel_compiles_for_v5e(one_chip, num_funcs, num_events):
     assert labels.shape == (num_events,) and labels.dtype == jnp.int8
 
 
-def test_decode_step_expert_casts_keep_their_scope_for_v5e(one_chip):
-    """At published widths, each float32->bfloat16 convert of the stacked
-    expert weights (the decode step's largest device cost) keeps the
-    ``cast_params`` name scope through XLA's passes: ``decode_cast_share``
-    reads it from the ops' metadata in a profiler trace."""
+def _decode_step_for_v5e(one_chip, params_of):
+    """The served decode step at published widths, compiled for a described
+    v5e on ``params_of(cfg, params)``: (cfg, HLO text)."""
     from repro import configs
     from repro.launch.steps import (StepOptions, build_decode_step, build_prefill_step,
                                     make_shard_ctx)
@@ -77,7 +75,8 @@ def test_decode_step_expert_casts_keep_their_scope_for_v5e(one_chip):
         return jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
 
-    params = on_chip(jax.eval_shape(lambda: init_params(cfg, jax.random.key(0))))
+    params = on_chip(jax.eval_shape(
+        lambda: params_of(cfg, init_params(cfg, jax.random.key(0)))))
     prompts = on_chip({"tokens": jax.ShapeDtypeStruct((batch, prompt), jnp.int32)})
     prefill = build_prefill_step(cfg, ctx, opts, max_seq=prompt + 128)
     cache = on_chip(jax.eval_shape(prefill, params, prompts)[1])
@@ -85,6 +84,22 @@ def test_decode_step_expert_casts_keep_their_scope_for_v5e(one_chip):
     text = jax.jit(build_decode_step(cfg, ctx, opts), donate_argnums=(1,)).lower(
         params, cache, tokens).compile().as_text()
     expert = re.compile(rf"= bf16\[{cfg.n_layers},{cfg.moe_experts},\d+,\d+\]\S* convert\(")
-    casts = [line for line in text.splitlines() if expert.search(line)]
+    return [line for line in text.splitlines() if expert.search(line)]
+
+
+def test_decode_step_expert_casts_keep_their_scope_for_v5e(one_chip):
+    """At published widths, each float32->bfloat16 convert of the stacked
+    expert weights (the decode step's largest device cost) keeps the
+    ``cast_params`` name scope through XLA's passes: ``decode_cast_share``
+    reads it from the ops' metadata in a profiler trace."""
+    casts = _decode_step_for_v5e(one_chip, lambda cfg, p: p)
     assert len(casts) == 3  # gate, up, down
     assert all('op_name="jit(decode_step)/decode/cast_params/' in line for line in casts)
+
+
+def test_decode_step_on_compute_params_casts_no_expert_weight_for_v5e(one_chip):
+    """On serve()'s compute-dtype copy the decode step converts no expert
+    weight: the three casts above leave the compiled program."""
+    from repro.models.model import compute_params
+
+    assert _decode_step_for_v5e(one_chip, compute_params) == []
