@@ -24,6 +24,7 @@ ARCHS = (
     "jamba_v01_52b",
     "hubert_xlarge",
     "qwen2_vl_2b",
+    "deepseek_v2_lite",
 )
 
 # canonical ids from the assignment (hyphens) -> module names
@@ -40,6 +41,9 @@ ALIASES.update(
         "jamba-v0.1-52b": "jamba_v01_52b",
         "hubert-xlarge": "hubert_xlarge",
         "qwen2-vl-2b": "qwen2_vl_2b",
+        "deepseek-v2-lite": "deepseek_v2_lite",
+        # one chip's share of it (configs/deepseek_v2_lite_ep8.py), not a cell arch
+        "deepseek-v2-lite-ep8": "deepseek_v2_lite_ep8",
     }
 )
 
@@ -73,9 +77,11 @@ def smoke(name: str) -> ModelConfig:
 def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
     """Same family/layout, tiny dims: one CPU forward/train step must run."""
     pairs = 8  # qk_dim // 2 after reduction
+    experts = min(cfg.moe_experts, 8) if cfg.moe_experts else 0
+    scale = experts / cfg.moe_experts if cfg.moe_experts else 0  # a share keeps its ratio
     return dataclasses.replace(
         cfg,
-        n_layers=cfg.period * 2,
+        n_layers=cfg.first_k_dense + cfg.period * 2,
         d_model=64,
         n_heads=4,
         n_kv_heads=min(cfg.n_kv_heads, 2),
@@ -88,9 +94,12 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
         qk_nope_dim=8 if cfg.qk_nope_dim else 0,
         qk_rope_dim=8 if cfg.qk_rope_dim else 0,
         v_head_dim=16 if cfg.v_head_dim else 0,
-        moe_experts=min(cfg.moe_experts, 8) if cfg.moe_experts else 0,
+        moe_experts=experts,
         moe_topk=min(cfg.moe_topk, 2) if cfg.moe_topk else 0,
         moe_dff=32 if cfg.moe_dff else 0,
+        moe_shared_dff=64 if cfg.moe_shared_dff else 0,
+        moe_n_held=max(1, int(cfg.moe_n_held * scale)) if cfg.moe_n_held else 0,
+        moe_held_offset=int(cfg.moe_held_offset * scale),
         ssm_d_state=8,
         ssm_dt_rank=8,
         mrope_sections=(2, 3, 3),

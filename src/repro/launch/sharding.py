@@ -27,9 +27,11 @@ _COL = frozenset(
 _ROW = frozenset({"wo", "out_proj", "x_proj", "w_down", "A_log"})
 _VEC_MODEL = frozenset({"conv_b", "dt_bias", "D"})  # d_inner-length vectors
 _EXPERT = frozenset({"moe_gate", "moe_up", "moe_down"})
+# The shared experts are replicated over the model axis like the router:
+# every expert-parallel shard computes them alike (models/moe.py).
 _REP = frozenset(
     {"ln1", "ln2", "post_ln1", "post_ln2", "q_ln", "kv_ln", "final_ln",
-     "router", "wdkv"}
+     "router", "wdkv", "shared_gate", "shared_up", "shared_down"}
 )
 
 
@@ -130,7 +132,7 @@ def param_shardings(
             if isinstance(p_, jax.tree_util.DictKey):
                 key = p_.key
             if isinstance(p_, (jax.tree_util.SequenceKey,)):
-                stacked = True  # inside params["layers"][pos]
+                stacked = True  # inside params["layers"][pos] or params["lead"][0]
         spec = param_pspec(key, leaf.shape, tp, dpa, dp, stacked, fsdp, mamba_tp)
         return NamedSharding(mesh, spec)
 

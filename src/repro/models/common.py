@@ -50,7 +50,14 @@ class ModelConfig:
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)  # pairs per (t, h, w)
     # activation
     activation: str = "silu"  # silu (swiglu) | geglu | gelu (dense, no gate)
-    # MLA (DeepSeek/MiniCPM3-style latent attention)
+    # YaRN context extension of the rope frequencies (factor 1: plain rope)
+    yarn_factor: float = 1.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    # MLA (DeepSeek/MiniCPM3-style latent attention); q_lora_rank 0: plain wq
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
@@ -61,6 +68,15 @@ class ModelConfig:
     moe_topk: int = 0
     moe_dff: int = 0
     moe_capacity_factor: float = 1.25
+    moe_norm_topk: bool = True  # renormalise the top-k weights to sum 1
+    moe_shared_dff: int = 0  # width of the shared experts, as one SwiGLU
+    # Expert parallelism: the experts held here, [offset, offset + n_held)
+    # of moe_experts; 0 holds all of them.  Held experts are drawn one key
+    # per global expert id, so a share is a slice of the whole layer.
+    moe_n_held: int = 0
+    moe_held_offset: int = 0
+    # leading dense layers ahead of the period (layout[0]'s mixer, a d_ff MLP)
+    first_k_dense: int = 0
     # Mamba (SSM)
     ssm_d_state: int = 16
     ssm_d_conv: int = 4
@@ -91,8 +107,18 @@ class ModelConfig:
 
     @property
     def n_periods(self) -> int:
-        assert self.n_layers % self.period == 0, (self.name, self.n_layers, self.period)
-        return self.n_layers // self.period
+        n = self.n_layers - self.first_k_dense
+        assert n % self.period == 0, (self.name, self.n_layers, self.period)
+        return n // self.period
+
+    @property
+    def lead_spec(self) -> LayerSpec:
+        """The leading dense layers' kind."""
+        return LayerSpec(self.layout[0].mixer, DENSE)
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.moe_n_held or self.moe_experts
 
     @property
     def d_inner(self) -> int:
@@ -125,45 +151,53 @@ class ModelConfig:
         """Eligible for long_500k (SSM / hybrid-with-few-attn / pure-SWA)."""
         return all(s.mixer in (MAMBA, SWA) for s in self.layout) or self.family == "hybrid"
 
-    def n_params(self) -> int:
-        """Analytic parameter count (for roofline MODEL_FLOPS)."""
+    def _layer_params(self, spec: LayerSpec) -> int:
         d, f = self.d_model, self.d_ff
-        v = self.vocab_padded
-        total = v * d  # embedding
-        if not self.tie_embeddings:
-            total += v * d
-        for spec in self.layout:
-            n = 0
-            if spec.mixer in (FULL, SWA):
-                n += d * self.n_heads * self.head_dim  # q
-                n += 2 * d * self.n_kv_heads * self.head_dim  # k, v
-                n += self.n_heads * self.head_dim * d  # o
-            elif spec.mixer == MLA:
-                qh = self.qk_nope_dim + self.qk_rope_dim
+        n = 0
+        if spec.mixer in (FULL, SWA):
+            n += d * self.n_heads * self.head_dim  # q
+            n += 2 * d * self.n_kv_heads * self.head_dim  # k, v
+            n += self.n_heads * self.head_dim * d  # o
+        elif spec.mixer == MLA:
+            qh = self.qk_nope_dim + self.qk_rope_dim
+            if self.q_lora_rank:
                 n += d * self.q_lora_rank + self.q_lora_rank * self.n_heads * qh
-                n += d * (self.kv_lora_rank + self.qk_rope_dim)
-                n += self.kv_lora_rank * self.n_heads * (self.qk_nope_dim + self.v_head_dim)
-                n += self.n_heads * self.v_head_dim * d
-            elif spec.mixer == MAMBA:
-                di = self.d_inner
-                n += d * 2 * di + di * self.ssm_d_conv + di  # in_proj, conv_w, conv_b
-                n += di * (self.dt_rank + 2 * self.ssm_d_state)  # x_proj
-                n += self.dt_rank * di + di  # dt_proj, dt_bias
-                n += di * self.ssm_d_state + di  # A_log, D
-                n += di * d  # out_proj
-            if spec.mlp == DENSE:
-                n += (3 if self.activation in ("silu", "geglu") else 2) * d * f
-            elif spec.mlp == MOE:
-                n += d * self.moe_experts
-                n += self.moe_experts * 3 * d * self.moe_dff
-            n += d  # ln1
-            if spec.mlp != NONE:
-                n += d  # ln2
-            if self.sandwich_norm:
-                n += d + (d if spec.mlp != NONE else 0)
-            if spec.mixer == MLA:
-                n += self.q_lora_rank + self.kv_lora_rank  # q_ln, kv_ln
-            total += n * self.n_periods
+                n += self.q_lora_rank  # q_ln
+            else:
+                n += d * self.n_heads * qh
+            n += d * (self.kv_lora_rank + self.qk_rope_dim)
+            n += self.kv_lora_rank * self.n_heads * (self.qk_nope_dim + self.v_head_dim)
+            n += self.n_heads * self.v_head_dim * d
+            n += self.kv_lora_rank  # kv_ln
+        elif spec.mixer == MAMBA:
+            di = self.d_inner
+            n += d * 2 * di + di * self.ssm_d_conv + di  # in_proj, conv_w, conv_b
+            n += di * (self.dt_rank + 2 * self.ssm_d_state)  # x_proj
+            n += self.dt_rank * di + di  # dt_proj, dt_bias
+            n += di * self.ssm_d_state + di  # A_log, D
+            n += di * d  # out_proj
+        if spec.mlp == DENSE:
+            n += (3 if self.activation in ("silu", "geglu") else 2) * d * f
+        elif spec.mlp == MOE:
+            n += d * self.moe_experts
+            n += self.n_experts_held * 3 * d * self.moe_dff
+            n += 3 * d * self.moe_shared_dff
+        n += d  # ln1
+        if spec.mlp != NONE:
+            n += d  # ln2
+        if self.sandwich_norm:
+            n += d + (d if spec.mlp != NONE else 0)
+        return n
+
+    def n_params(self) -> int:
+        """Analytic parameter count (for roofline MODEL_FLOPS): the experts
+        held here, the leading dense layers, the periods."""
+        d = self.d_model
+        total = self.vocab_padded * d  # embedding
+        if not self.tie_embeddings:
+            total += self.vocab_padded * d
+        total += self.first_k_dense * self._layer_params(self.lead_spec)
+        total += self.n_periods * sum(self._layer_params(s) for s in self.layout)
         total += d  # final_ln
         return total
 
@@ -172,7 +206,7 @@ class ModelConfig:
         if not self.mlp_has(MOE):
             return self.n_params()
         full = self.n_params()
-        per_layer_moe = self.moe_experts * 3 * self.d_model * self.moe_dff
+        per_layer_moe = self.n_experts_held * 3 * self.d_model * self.moe_dff
         n_moe_layers = sum(1 for s in self.layout if s.mlp == MOE) * self.n_periods
         inactive = per_layer_moe * (1 - self.moe_topk / self.moe_experts)
         return int(full - n_moe_layers * inactive)
@@ -199,9 +233,12 @@ def init_layer_params(cfg: ModelConfig, spec: LayerSpec, key) -> Dict[str, Any]:
     elif spec.mixer == MLA:
         H = cfg.n_heads
         qh = cfg.qk_nope_dim + cfg.qk_rope_dim
-        p["wdq"] = _dense_init(next(ks), (d, cfg.q_lora_rank), dt)
-        p["q_ln"] = jnp.ones((cfg.q_lora_rank,), dt)
-        p["wuq"] = _dense_init(next(ks), (cfg.q_lora_rank, H * qh), dt)
+        if cfg.q_lora_rank:
+            p["wdq"] = _dense_init(next(ks), (d, cfg.q_lora_rank), dt)
+            p["q_ln"] = jnp.ones((cfg.q_lora_rank,), dt)
+            p["wuq"] = _dense_init(next(ks), (cfg.q_lora_rank, H * qh), dt)
+        else:
+            p["wq"] = _dense_init(next(ks), (d, H * qh), dt)
         p["wdkv"] = _dense_init(next(ks), (d, cfg.kv_lora_rank + cfg.qk_rope_dim), dt)
         p["kv_ln"] = jnp.ones((cfg.kv_lora_rank,), dt)
         p["wuk"] = _dense_init(next(ks), (cfg.kv_lora_rank, H * cfg.qk_nope_dim), dt)
@@ -240,9 +277,24 @@ def init_layer_params(cfg: ModelConfig, spec: LayerSpec, key) -> Dict[str, Any]:
         E, f = cfg.moe_experts, cfg.moe_dff
         p["ln2"] = jnp.ones((d,), dt)
         p["router"] = _dense_init(next(ks), (d, E), dt)
-        p["moe_gate"] = _dense_init(next(ks), (E, d, f), dt)
-        p["moe_up"] = _dense_init(next(ks), (E, d, f), dt)
-        p["moe_down"] = _dense_init(next(ks), (E, f, d), dt)
+        if cfg.moe_n_held:
+            ids = jnp.arange(cfg.moe_held_offset, cfg.moe_held_offset + cfg.moe_n_held)
+
+            def experts(k, shape):  # expert i from fold_in(k, i): a share is a slice
+                return jax.vmap(lambda i: _dense_init(jax.random.fold_in(k, i), shape, dt))(ids)
+
+            p["moe_gate"] = experts(next(ks), (d, f))
+            p["moe_up"] = experts(next(ks), (d, f))
+            p["moe_down"] = experts(next(ks), (f, d))
+        else:
+            p["moe_gate"] = _dense_init(next(ks), (E, d, f), dt)
+            p["moe_up"] = _dense_init(next(ks), (E, d, f), dt)
+            p["moe_down"] = _dense_init(next(ks), (E, f, d), dt)
+        if cfg.moe_shared_dff:
+            fs = cfg.moe_shared_dff
+            p["shared_gate"] = _dense_init(next(ks), (d, fs), dt)
+            p["shared_up"] = _dense_init(next(ks), (d, fs), dt)
+            p["shared_down"] = _dense_init(next(ks), (fs, d), dt)
     if cfg.sandwich_norm:
         p["post_ln1"] = jnp.ones((d,), dt)
         if spec.mlp != NONE:
@@ -251,7 +303,9 @@ def init_layer_params(cfg: ModelConfig, spec: LayerSpec, key) -> Dict[str, Any]:
 
 
 def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
-    """Full parameter pytree. Layer params stacked over periods per position."""
+    """Full parameter pytree. Layer params stacked over periods per position;
+    the leading dense layers, where the config has them, under ``lead`` as
+    a one-position layout stacked over ``first_k_dense``."""
     keys = jax.random.split(key, cfg.period + 3)
     params: Dict[str, Any] = {
         # 1/sqrt(d) keeps tied-unembed logits O(1) at init (emb_scale archs
@@ -266,6 +320,9 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
         params["unembed"] = _dense_init(
             keys[-2], (cfg.d_model, cfg.vocab_padded), cfg.param_dtype
         )
+    if cfg.first_k_dense:
+        lkeys = jax.random.split(keys[-3], cfg.first_k_dense)
+        params["lead"] = [jax.vmap(lambda k: init_layer_params(cfg, cfg.lead_spec, k))(lkeys)]
     layers = []
     for pos, spec in enumerate(cfg.layout):
         pkeys = jax.random.split(keys[pos], cfg.n_periods)

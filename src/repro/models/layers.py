@@ -37,13 +37,48 @@ def softcap(x: jnp.ndarray, cap: float) -> jnp.ndarray:
 
 # ------------------------------------------------------------------- RoPE
 def rope_cos_sin(
-    positions: jnp.ndarray, dim: int, theta: float = 10000.0
+    positions: jnp.ndarray, dim: int, theta: float = 10000.0,
+    inv_freq: Optional[jnp.ndarray] = None, scale: float = 1.0,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """positions (...,) -> cos/sin (..., dim//2)."""
+    """positions (...,) -> cos/sin (..., dim//2), θ^(−2i/dim) unless
+    ``inv_freq`` is given, both times ``scale``."""
     half = dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term: 0.1·mscale·ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(
+    beta_fast: float, beta_slow: float, dim: int, theta: float, original_max_pos: int
+) -> Tuple[int, int]:
+    """The pair indices between which YaRN ramps from the plain to the
+    interpolated frequency (DeepSeek's ``yarn_find_correction_range``)."""
+
+    def pair(rotations):
+        return dim * math.log(original_max_pos / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    return max(math.floor(pair(beta_fast)), 0), min(math.ceil(pair(beta_slow)), dim - 1)
+
+
+def yarn_inv_freq(
+    dim: int, theta: float, factor: float, original_max_pos: int,
+    beta_fast: float, beta_slow: float,
+) -> jnp.ndarray:
+    """YaRN inverse frequencies over ``dim`` rope dims: θ^(−2i/dim) below
+    the correction range, θ^(−2i/dim)/factor above it, a linear ramp
+    between."""
+    half = dim // 2
+    lo, hi = yarn_correction_range(beta_fast, beta_slow, dim, theta, original_max_pos)
+    hi = hi + 0.001 if lo == hi else hi
+    extra = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - lo) / (hi - lo), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
 
 
 def apply_rope(

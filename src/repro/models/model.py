@@ -15,7 +15,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -67,7 +67,7 @@ class ShardCtx:
         if self.mesh is None or self.model_axis is None:
             return None
         n = self.mesh.shape[self.model_axis]
-        if cfg.moe_experts % n != 0:
+        if cfg.n_experts_held % n != 0:
             return None
         return EPInfo(axis=self.model_axis, n_shards=n)
 
@@ -95,12 +95,38 @@ def _positions(cfg: ModelConfig, batch, B: int, S: int, offset=0) -> jnp.ndarray
     return L.text_positions(B, S, offset)
 
 
-def _rope_cos_sin(cfg: ModelConfig, positions, dim: int):
+def _rope_cos_sin(cfg: ModelConfig, positions):
+    """cos/sin over the rotated dims: a head's, or MLA's ``qk_rope_dim``;
+    YaRN's frequencies and cos/sin scale where ``cfg.yarn_factor`` > 1."""
+    dim = cfg.qk_rope_dim if cfg.mixer_has(MLA) else cfg.qk_dim
     if cfg.pos == "mrope":
         return L.mrope_cos_sin(positions, dim, cfg.mrope_sections, cfg.rope_theta)
     if cfg.pos == "none":
         return None, None
+    if cfg.yarn_factor > 1:
+        f = cfg.yarn_factor
+        inv_freq = L.yarn_inv_freq(dim, cfg.rope_theta, f, cfg.yarn_original_max_pos,
+                                   cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+        scale = L.yarn_mscale(f, cfg.yarn_mscale) / L.yarn_mscale(f, cfg.yarn_mscale_all_dim)
+        return L.rope_cos_sin(positions, dim, inv_freq=inv_freq, scale=scale)
     return L.rope_cos_sin(positions, dim, cfg.rope_theta)
+
+
+def _mla_scale(cfg: ModelConfig) -> float:
+    """MLA's softmax scale, 1/sqrt(qk head dim), times YaRN's m² where the
+    config gives ``yarn_mscale_all_dim``."""
+    m = L.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) if cfg.yarn_mscale_all_dim else 1.0
+    return m * m / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+
+
+def _mla_q(cfg: ModelConfig, p, h):
+    """MLA queries (B, S, H, nope + rope): through the q latent, or a plain
+    ``wq`` where the config has no q compression."""
+    if cfg.q_lora_rank:
+        q = L.rms_norm(h @ p["wdq"], p["q_ln"], cfg.norm_eps) @ p["wuq"]
+    else:
+        q = h @ p["wq"]
+    return q.reshape(h.shape[0], h.shape[1], cfg.n_heads, -1)
 
 
 def unembed(cfg: ModelConfig, params, x: jnp.ndarray) -> jnp.ndarray:
@@ -125,15 +151,26 @@ def _cast_block_params(p: Dict[str, jnp.ndarray], dtype) -> Dict[str, jnp.ndarra
     return {k: (v if k in _F32_KEYS else v.astype(dtype)) for k, v in p.items()}
 
 
-@functools.partial(jax.jit, static_argnums=0)
 def compute_params(cfg: ModelConfig, params):
     """The compute-dtype copy of what ``prefill`` and ``decode_step`` cast:
     the layers as ``_cast_block_params`` casts them, and the embedding and
     unembedding, which are cast right after every read.  Every other leaf
     is kept as it is.  On this copy the steps' casts are no-ops, so a
-    server that never changes its weights casts them once, not per step."""
+    server that never changes its weights casts them once, not per step.
+    Where every such weight is already in the compute dtype, ``params``
+    itself: no second copy."""
+    copy = jax.eval_shape(functools.partial(_compute_copy, cfg), params)
+    if all(c.dtype == p.dtype for c, p in zip(jax.tree.leaves(copy), jax.tree.leaves(params))):
+        return params
+    return _compute_copy(cfg, params)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _compute_copy(cfg: ModelConfig, params):
     out = dict(params)
     out["layers"] = [_cast_block_params(p, cfg.compute_dtype) for p in params["layers"]]
+    if "lead" in params:
+        out["lead"] = [_cast_block_params(p, cfg.compute_dtype) for p in params["lead"]]
     for k in ("embed", "unembed"):
         if k in params:
             out[k] = params[k].astype(cfg.compute_dtype)
@@ -240,61 +277,58 @@ def _mla_seq(cfg, spec, p, h, cos, sin, ctx, with_cache=False):
     B, S, D = h.shape
     H = cfg.n_heads
     nope, rope, vh = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    cq = L.rms_norm(h @ p["wdq"], p["q_ln"], cfg.norm_eps)
-    q = (cq @ p["wuq"]).reshape(B, S, H, nope + rope)
+    q = _mla_q(cfg, p, h)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     dkv = h @ p["wdkv"]  # (B,S,kvr+rope)
     ckv = L.rms_norm(dkv[..., : cfg.kv_lora_rank], p["kv_ln"], cfg.norm_eps)
     k_rope = dkv[..., cfg.kv_lora_rank :].reshape(B, S, 1, rope)
     if cos is not None:
-        cr, sr = cos[..., : rope // 2], sin[..., : rope // 2]
-        q_rope = L.apply_rope(q_rope, cr, sr)
-        k_rope = L.apply_rope(k_rope, cr, sr)
-    k_nope = (ckv @ p["wuk"]).reshape(B, S, H, nope)
-    v = (ckv @ p["wuv"]).reshape(B, S, H, vh)
-    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, S, H, rope))], -1)
-    q = jnp.concatenate([q_nope, q_rope], -1)
-    scale = 1.0 / math.sqrt(nope + rope)
-    if _use_seq_parallel(ctx, S):
-        out = _attention_seq_parallel(
-            q, k, v, ctx, causal=cfg.causal, window=0, cap=cfg.attn_softcap,
-            scale=scale,
-        )
-    else:
-        out = L.attention(q, k, v, causal=cfg.causal, window=0, cap=cfg.attn_softcap,
-                          scale=scale,
-                          direct_threshold=(1 << 30) if ctx.unroll else 1024)
+        q_rope = L.apply_rope(q_rope, cos, sin)
+        k_rope = L.apply_rope(k_rope, cos, sin)
+    # The latent part: keys and values expanded from the latent, attended.
+    with jax.named_scope("latent_attention"):
+        k_nope = (ckv @ p["wuk"]).reshape(B, S, H, nope)
+        v = (ckv @ p["wuv"]).reshape(B, S, H, vh)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, S, H, rope))], -1)
+        q = jnp.concatenate([q_nope, q_rope], -1)
+        scale = _mla_scale(cfg)
+        if _use_seq_parallel(ctx, S):
+            out = _attention_seq_parallel(
+                q, k, v, ctx, causal=cfg.causal, window=0, cap=cfg.attn_softcap,
+                scale=scale,
+            )
+        else:
+            out = L.attention(q, k, v, causal=cfg.causal, window=0, cap=cfg.attn_softcap,
+                              scale=scale,
+                              direct_threshold=(1 << 30) if ctx.unroll else 1024)
     out = out.reshape(B, S, H * vh) @ p["wo"]
     if with_cache:
         return out, (ckv, k_rope[:, :, 0, :])
     return out
 
 
+_MOE_KEYS = ("router", "moe_gate", "moe_up", "moe_down",
+             "shared_gate", "shared_up", "shared_down")
+
+
 def _mlp_apply(cfg, spec, p, h, ctx: ShardCtx):
     with jax.named_scope("moe" if spec.mlp == MOE else "mlp"):
         if spec.mlp == MOE:
             ep = ctx.ep_info(cfg)
+            sub = {k2: p[k2] for k2 in _MOE_KEYS if k2 in p}
             if ep is not None:
                 fn = shard_map(
                     lambda pr, xr: moe_block(pr, xr, cfg, ep),
                     mesh=ctx.mesh,
                     in_specs=(
-                        {
-                            "router": P(),
-                            "moe_gate": P(ctx.model_axis),
-                            "moe_up": P(ctx.model_axis),
-                            "moe_down": P(ctx.model_axis),
-                        },
+                        {k2: P(ctx.model_axis) if k2.startswith("moe_") else P()
+                         for k2 in sub},
                         P(*ctx.token_pspec, None, None),
                     ),
                     out_specs=P(*ctx.token_pspec, None, None),
                 )
-                sub = {k2: p[k2] for k2 in ("router", "moe_gate", "moe_up", "moe_down")}
                 return fn(sub, h)
-            return moe_block(
-                {k2: p[k2] for k2 in ("router", "moe_gate", "moe_up", "moe_down")},
-                h, cfg, None,
-            )
+            return moe_block(sub, h, cfg, None)
         return L.mlp(p, h, cfg.activation)
 
 
@@ -330,30 +364,39 @@ def apply_block(cfg, spec: LayerSpec, p, x, cos, sin, ctx: ShardCtx) -> jnp.ndar
 
 
 # ----------------------------------------------------------------- forward
+def _stacks(cfg: ModelConfig):
+    """(key, layout) of each scanned stack of layers, in order: the leading
+    dense layers where the config has them, then the periods.  Params and
+    caches hold a stack's per-position entries under its key."""
+    lead = [("lead", (cfg.lead_spec,))] if cfg.first_k_dense else []
+    return lead + [("layers", cfg.layout)]
+
+
 def hidden_states(cfg: ModelConfig, params, batch, ctx: ShardCtx = ShardCtx()) -> jnp.ndarray:
     x = embed_tokens(cfg, params, batch)
     B, S, _ = x.shape
     positions = _positions(cfg, batch, B, S)
-    cos, sin = _rope_cos_sin(cfg, positions, cfg.qk_dim)
+    cos, sin = _rope_cos_sin(cfg, positions)
     x = ctx.constrain(x, ctx.hidden_spec())
 
-    def body(xc, period_params):
-        for pos, spec in enumerate(cfg.layout):
-            if ctx.remat == "block" and cfg.period > 1:
-                # nested remat: multi-layer periods (jamba: 8 layers) would
-                # otherwise hold the whole period's intermediates in the
-                # backward working set (measured 25 GiB of temps at 52B)
-                blk = jax.checkpoint(
-                    lambda pp, xx, s=spec: apply_block(cfg, s, pp, xx, cos, sin, ctx)
-                )
-                xc = blk(period_params[pos], xc)
-            else:
-                xc = apply_block(cfg, spec, period_params[pos], xc, cos, sin, ctx)
-        return xc, None
+    for key, layout in _stacks(cfg):
+        def body(xc, period_params, layout=layout):
+            for pos, spec in enumerate(layout):
+                if ctx.remat == "block" and len(layout) > 1:
+                    # nested remat: multi-layer periods (jamba: 8 layers) would
+                    # otherwise hold the whole period's intermediates in the
+                    # backward working set (measured 25 GiB of temps at 52B)
+                    blk = jax.checkpoint(
+                        lambda pp, xx, s=spec: apply_block(cfg, s, pp, xx, cos, sin, ctx)
+                    )
+                    xc = blk(period_params[pos], xc)
+                else:
+                    xc = apply_block(cfg, spec, period_params[pos], xc, cos, sin, ctx)
+            return xc, None
 
-    if ctx.remat == "block":
-        body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(body, x, params["layers"], unroll=ctx.scan_unroll)
+        if ctx.remat == "block":
+            body = jax.checkpoint(body)
+        x, _ = jax.lax.scan(body, x, params[key], unroll=ctx.scan_unroll)
     return x
 
 
@@ -402,31 +445,35 @@ def loss_and_metrics(
 
 
 # ------------------------------------------------------------------- cache
-def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> Dict[str, Any]:
-    """Zeroed decode cache; stacked over periods per layout position."""
-    NP = cfg.n_periods
+def _layer_cache(cfg: ModelConfig, spec: LayerSpec, n: int, batch: int, max_seq: int):
+    """Zeroed decode cache of one layout position, stacked over ``n`` layers."""
     dt = cfg.compute_dtype
-    per_pos: List[Dict[str, jnp.ndarray]] = []
-    for spec in cfg.layout:
-        if spec.mixer == MAMBA:
-            c = {
-                "h": jnp.zeros((NP, batch, cfg.d_inner, cfg.ssm_d_state), jnp.float32),
-                "conv": jnp.zeros((NP, batch, cfg.ssm_d_conv - 1, cfg.d_inner), dt),
-            }
-        elif spec.mixer == MLA:
-            c = {
-                "ckv": jnp.zeros((NP, batch, max_seq, cfg.kv_lora_rank), dt),
-                "krope": jnp.zeros((NP, batch, max_seq, cfg.qk_rope_dim), dt),
-            }
-        else:
-            Sc = min(max_seq, cfg.window) if spec.mixer == SWA else max_seq
-            c = {
-                "k": jnp.zeros((NP, batch, Sc, cfg.n_kv_heads, cfg.head_dim), dt),
-                "v": jnp.zeros((NP, batch, Sc, cfg.n_kv_heads, cfg.head_dim), dt),
-                "kpos": jnp.full((NP, Sc), -1, jnp.int32),
-            }
-        per_pos.append(c)
-    return {"pos": jnp.zeros((), jnp.int32), "layers": per_pos}
+    if spec.mixer == MAMBA:
+        return {
+            "h": jnp.zeros((n, batch, cfg.d_inner, cfg.ssm_d_state), jnp.float32),
+            "conv": jnp.zeros((n, batch, cfg.ssm_d_conv - 1, cfg.d_inner), dt),
+        }
+    if spec.mixer == MLA:
+        return {
+            "ckv": jnp.zeros((n, batch, max_seq, cfg.kv_lora_rank), dt),
+            "krope": jnp.zeros((n, batch, max_seq, cfg.qk_rope_dim), dt),
+        }
+    Sc = min(max_seq, cfg.window) if spec.mixer == SWA else max_seq
+    return {
+        "k": jnp.zeros((n, batch, Sc, cfg.n_kv_heads, cfg.head_dim), dt),
+        "v": jnp.zeros((n, batch, Sc, cfg.n_kv_heads, cfg.head_dim), dt),
+        "kpos": jnp.full((n, Sc), -1, jnp.int32),
+    }
+
+
+def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> Dict[str, Any]:
+    """Zeroed decode cache; stacked over periods per layout position, and
+    over the leading dense layers under ``lead``."""
+    out = {"pos": jnp.zeros((), jnp.int32),
+           "layers": [_layer_cache(cfg, s, cfg.n_periods, batch, max_seq) for s in cfg.layout]}
+    if cfg.first_k_dense:
+        out["lead"] = [_layer_cache(cfg, cfg.lead_spec, cfg.first_k_dense, batch, max_seq)]
+    return out
 
 
 def _attn_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx):
@@ -536,7 +583,7 @@ def _mla_decode_sharded(cfg, p, q_eff, q_rope, ckv_new, krope_new, cache, pos, c
     kvr, vh = cfg.kv_lora_rank, cfg.v_head_dim
     b = ctx.batch_axes if ctx.batch_shardable else None
     m_ax = ctx.model_axis
-    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    scale = _mla_scale(cfg)
 
     def f(qe, qr_, cn, kn, cc, kc, posr):
         i = jax.lax.axis_index(m_ax)
@@ -590,16 +637,14 @@ def _mla_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx):
     B = h.shape[0]
     H = cfg.n_heads
     nope, rope, vh, kvr = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim, cfg.kv_lora_rank
-    cq = L.rms_norm(h @ p["wdq"], p["q_ln"], cfg.norm_eps)
-    q = (cq @ p["wuq"]).reshape(B, 1, H, nope + rope)
+    q = _mla_q(cfg, p, h)  # (B,1,H,nope+rope)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     dkv = h @ p["wdkv"]
     ckv_new = L.rms_norm(dkv[..., :kvr], p["kv_ln"], cfg.norm_eps)  # (B,1,kvr)
     krope_new = dkv[..., kvr:].reshape(B, 1, 1, rope)
     if cos is not None:
-        cr, sr = cos[..., : rope // 2], sin[..., : rope // 2]
-        q_rope = L.apply_rope(q_rope, cr, sr)
-        krope_new = L.apply_rope(krope_new, cr, sr)
+        q_rope = L.apply_rope(q_rope, cos, sin)
+        krope_new = L.apply_rope(krope_new, cos, sin)
     with jax.named_scope("kv_update"):
         ckv = jax.lax.dynamic_update_index_in_dim(cache["ckv"], ckv_new[:, 0], pos, axis=1)
         krope = jax.lax.dynamic_update_index_in_dim(
@@ -608,17 +653,19 @@ def _mla_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx):
     # absorb W_uk into q:  q_eff (B,1,H,kvr)
     wuk = p["wuk"].reshape(kvr, H, nope)
     q_eff = jnp.einsum("bqhn,khn->bqhk", q_nope, wuk)
-    scores = jnp.einsum("bqhk,bsk->bhqs", q_eff.astype(jnp.float32), ckv.astype(jnp.float32))
-    scores += jnp.einsum(
-        "bqhr,bsr->bhqs", q_rope.astype(jnp.float32), krope.astype(jnp.float32)
-    )
-    scores *= 1.0 / math.sqrt(nope + rope)
-    scores = L.softcap(scores, cfg.attn_softcap)
-    Sc = ckv.shape[1]
-    valid = jnp.arange(Sc)[None, None, None, :] <= pos
-    scores = jnp.where(valid, scores, L.NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    lat = jnp.einsum("bhqs,bsk->bqhk", probs, ckv.astype(jnp.float32))  # (B,1,H,kvr)
+    # The latent part: scores, softmax and values over the latent cache.
+    with jax.named_scope("latent_attention"):
+        scores = jnp.einsum("bqhk,bsk->bhqs", q_eff.astype(jnp.float32), ckv.astype(jnp.float32))
+        scores += jnp.einsum(
+            "bqhr,bsr->bhqs", q_rope.astype(jnp.float32), krope.astype(jnp.float32)
+        )
+        scores *= _mla_scale(cfg)
+        scores = L.softcap(scores, cfg.attn_softcap)
+        Sc = ckv.shape[1]
+        valid = jnp.arange(Sc)[None, None, None, :] <= pos
+        scores = jnp.where(valid, scores, L.NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1)
+        lat = jnp.einsum("bhqs,bsk->bqhk", probs, ckv.astype(jnp.float32))  # (B,1,H,kvr)
     wuv = p["wuv"].reshape(kvr, H, vh)
     out = jnp.einsum("bqhk,khv->bqhv", lat, wuv).reshape(B, 1, H * vh).astype(h.dtype)
     return out @ p["wo"], {"ckv": ckv, "krope": krope}
@@ -652,8 +699,9 @@ def decode_step(
     """One decode step: tokens (B, 1) -> (logits (B, 1, V), new cache).
 
     Its ops carry the name scopes ``embed``, ``cast_params``, ``attention``
-    (with ``kv_update``), ``moe`` and ``lm_head``: stable names for a
-    profiler trace's per-op reduction."""
+    (with ``kv_update``, and ``latent_attention`` in MLA), ``moe`` (with
+    ``routed_experts`` and ``shared_expert``) or ``mlp``, and ``lm_head``:
+    stable names for a profiler trace's per-op reduction."""
     pos = cache["pos"]
     with jax.named_scope("embed"):
         x = embed_tokens(cfg, params, {"tokens": tokens})
@@ -662,36 +710,36 @@ def decode_step(
         jnp.broadcast_to(pos, (3, B, 1)) if cfg.pos == "mrope"
         else jnp.full((B, 1), pos)
     )
-    cos, sin = _rope_cos_sin(cfg, positions, cfg.qk_dim)
-    # The compute-dtype copies of every layer's weights, made before the
-    # layer scan: XLA hoists a cast out of the scan, and the cast it
-    # hoists keeps no name scope.  On ``compute_params``' copy there is
-    # nothing to cast.
-    with jax.named_scope("cast_params"):
-        layers = [_cast_block_params(p, cfg.compute_dtype) for p in params["layers"]]
+    cos, sin = _rope_cos_sin(cfg, positions)
+    new = {"pos": pos + 1}
+    for key, layout in _stacks(cfg):
+        # The compute-dtype copies of every layer's weights, made before the
+        # layer scan: XLA hoists a cast out of the scan, and the cast it
+        # hoists keeps no name scope.  On ``compute_params``' copy there is
+        # nothing to cast.
+        with jax.named_scope("cast_params"):
+            layers = [_cast_block_params(p, cfg.compute_dtype) for p in params[key]]
 
-    def body(xc, slices):
-        period_params, period_cache = slices
-        new_caches = []
-        for i, spec in enumerate(cfg.layout):
-            xc, nc = decode_block(
-                cfg, spec, period_params[i], xc, period_cache[i], pos, cos, sin, ctx
-            )
-            new_caches.append(nc)
-        return xc, new_caches
+        def body(xc, slices, layout=layout):
+            period_params, period_cache = slices
+            new_caches = []
+            for i, spec in enumerate(layout):
+                xc, nc = decode_block(
+                    cfg, spec, period_params[i], xc, period_cache[i], pos, cos, sin, ctx
+                )
+                new_caches.append(nc)
+            return xc, new_caches
 
-    x, new_layer_cache = jax.lax.scan(
-        body, x, (layers, cache["layers"]), unroll=ctx.scan_unroll
-    )
+        x, new[key] = jax.lax.scan(body, x, (layers, cache[key]), unroll=ctx.scan_unroll)
     with jax.named_scope("lm_head"):
         logits = unembed(cfg, params, x)
-    return logits, {"pos": pos + 1, "layers": new_layer_cache}
+    return logits, new
 
 
-def _expand_prefill_cache(cfg: ModelConfig, layer_caches, S: int, max_seq: int):
+def _expand_prefill_cache(cfg: ModelConfig, layout, layer_caches, S: int, max_seq: int):
     """Grow prefill caches to max_seq decode slots, ring-aligned for SWA."""
     out = []
-    for spec, c in zip(cfg.layout, layer_caches):
+    for spec, c in zip(layout, layer_caches):
         if spec.mixer == MAMBA:
             out.append(c)
             continue
@@ -731,6 +779,43 @@ def _expand_prefill_cache(cfg: ModelConfig, layer_caches, S: int, max_seq: int):
     return out
 
 
+def _prefill_block(cfg, spec, p, xc, cos, sin, ctx):
+    """One layer of a prefill: (new hidden states, the layer's cache)."""
+    S = xc.shape[1]
+    with jax.named_scope("cast_params"):
+        p = _cast_block_params(p, cfg.compute_dtype)
+    h = L.rms_norm(xc, p["ln1"], cfg.norm_eps)
+    with jax.named_scope("attention"):
+        if spec.mixer == MAMBA:
+            # full-sequence mixer; rebuild final state for the cache
+            hh = mamba_sequence(p, h, cfg, chunk=(h.shape[1] if ctx.unroll else 128))
+            cch = _mamba_prefill_state(cfg, p, h)
+            h = hh
+        elif spec.mixer == MLA:
+            h, (ckv, krope) = _mla_seq(cfg, spec, p, h, cos, sin, ctx, with_cache=True)
+            cch = {"ckv": ckv, "krope": krope}
+        else:
+            h, (k, v) = _attn_seq_with_cache(cfg, spec, p, h, cos, sin, ctx)
+            with jax.named_scope("kv_update"):
+                if spec.mixer == SWA:
+                    w = min(cfg.window, S)
+                    k, v = k[:, -w:], v[:, -w:]
+                    kpos = jnp.arange(S - w, S, dtype=jnp.int32)
+                else:
+                    kpos = jnp.arange(S, dtype=jnp.int32)
+                cch = {"k": k, "v": v, "kpos": kpos}
+    if cfg.sandwich_norm:
+        h = L.rms_norm(h, p["post_ln1"], cfg.norm_eps)
+    xc = xc + h
+    if spec.mlp != NONE:
+        h = L.rms_norm(xc, p["ln2"], cfg.norm_eps)
+        h = _mlp_apply(cfg, spec, p, h, ctx)
+        if cfg.sandwich_norm:
+            h = L.rms_norm(h, p["post_ln2"], cfg.norm_eps)
+        xc = xc + h
+    return ctx.constrain(xc, ctx.hidden_spec()), cch
+
+
 def prefill(
     cfg: ModelConfig, params, batch, ctx: ShardCtx = ShardCtx(),
     max_seq: Optional[int] = None,
@@ -741,56 +826,27 @@ def prefill(
         x = embed_tokens(cfg, params, batch)
     B, S, _ = x.shape
     positions = _positions(cfg, batch, B, S)
-    cos, sin = _rope_cos_sin(cfg, positions, cfg.qk_dim)
+    cos, sin = _rope_cos_sin(cfg, positions)
     x = ctx.constrain(x, ctx.hidden_spec())
 
-    def body(xc, period_params):
-        caches = []
-        for i, spec in enumerate(cfg.layout):
-            with jax.named_scope("cast_params"):
-                p = _cast_block_params(period_params[i], cfg.compute_dtype)
-            h = L.rms_norm(xc, p["ln1"], cfg.norm_eps)
-            with jax.named_scope("attention"):
-                if spec.mixer == MAMBA:
-                    # full-sequence mixer; rebuild final state for the cache
-                    hh = mamba_sequence(p, h, cfg, chunk=(h.shape[1] if ctx.unroll else 128))
-                    cch = _mamba_prefill_state(cfg, p, h)
-                    h = hh
-                elif spec.mixer == MLA:
-                    h, (ckv, krope) = _mla_seq(cfg, spec, p, h, cos, sin, ctx, with_cache=True)
-                    cch = {"ckv": ckv, "krope": krope}
-                else:
-                    h, (k, v) = _attn_seq_with_cache(cfg, spec, p, h, cos, sin, ctx)
-                    with jax.named_scope("kv_update"):
-                        if spec.mixer == SWA:
-                            w = min(cfg.window, S)
-                            k, v = k[:, -w:], v[:, -w:]
-                            kpos = jnp.arange(S - w, S, dtype=jnp.int32)
-                        else:
-                            kpos = jnp.arange(S, dtype=jnp.int32)
-                        cch = {"k": k, "v": v, "kpos": kpos}
-            if cfg.sandwich_norm:
-                h = L.rms_norm(h, p["post_ln1"], cfg.norm_eps)
-            xc = xc + h
-            if spec.mlp != NONE:
-                h = L.rms_norm(xc, p["ln2"], cfg.norm_eps)
-                h = _mlp_apply(cfg, spec, p, h, ctx)
-                if cfg.sandwich_norm:
-                    h = L.rms_norm(h, p["post_ln2"], cfg.norm_eps)
-                xc = xc + h
-            xc = ctx.constrain(xc, ctx.hidden_spec())
-            caches.append(cch)
-        return xc, caches
+    out = {"pos": jnp.asarray(S, jnp.int32)}
+    for key, layout in _stacks(cfg):
+        def body(xc, period_params, layout=layout):
+            caches = []
+            for i, spec in enumerate(layout):
+                xc, cch = _prefill_block(cfg, spec, period_params[i], xc, cos, sin, ctx)
+                caches.append(cch)
+            return xc, caches
 
-    if ctx.remat == "block":
-        body = jax.checkpoint(body)
-    x, layer_caches = jax.lax.scan(body, x, params["layers"], unroll=ctx.scan_unroll)
+        if ctx.remat == "block":
+            body = jax.checkpoint(body)
+        x, out[key] = jax.lax.scan(body, x, params[key], unroll=ctx.scan_unroll)
+        if max_seq is not None and max_seq != S:
+            with jax.named_scope("kv_update"):
+                out[key] = _expand_prefill_cache(cfg, layout, out[key], S, max_seq)
     with jax.named_scope("lm_head"):
         logits = unembed(cfg, params, x[:, -1:])
-    if max_seq is not None and max_seq != S:
-        with jax.named_scope("kv_update"):
-            layer_caches = _expand_prefill_cache(cfg, layer_caches, S, max_seq)
-    return logits, {"pos": jnp.asarray(S, jnp.int32), "layers": layer_caches}
+    return logits, out
 
 
 def _mamba_prefill_state(cfg, p, u):

@@ -50,27 +50,41 @@ def moe_block(
     cfg: ModelConfig,
     ep: Optional[EPInfo] = None,
 ) -> jnp.ndarray:
-    """Top-k routed expert MLP with capacity-based sort dispatch."""
+    """Top-k routed expert MLP with capacity-based sort dispatch, plus the
+    shared experts where the config has them.
+
+    The router scores all ``moe_experts``; this device computes the part of
+    the result its held experts give (``cfg.moe_n_held`` from
+    ``cfg.moe_held_offset``, split again over ``ep``'s shards), and the
+    shared experts' part once."""
     B, S, D = x.shape
+    xt = x.reshape(B * S, D)
+    with jax.named_scope("routed_experts"):
+        out = _routed(p, xt, cfg, ep)
+    if cfg.moe_shared_dff:
+        with jax.named_scope("shared_expert"):
+            h = jax.nn.silu(xt @ p["shared_gate"]) * (xt @ p["shared_up"])
+            out = out + h @ p["shared_down"]
+    return out.reshape(B, S, D)
+
+
+def _routed(p, xt, cfg: ModelConfig, ep: Optional[EPInfo]) -> jnp.ndarray:
+    N, D = xt.shape
     E, k = cfg.moe_experts, cfg.moe_topk
-    N = B * S
-    xt = x.reshape(N, D)
 
     # --- routing (replicated over the EP axis: cheap, avoids a broadcast) ---
     logits = (xt @ p["router"]).astype(jnp.float32)  # (N, E)
     probs = jax.nn.softmax(logits, axis=-1)
     weights, ids = jax.lax.top_k(probs, k)  # (N, k)
-    weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    if cfg.moe_norm_topk:
+        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
 
     # --- local expert ownership ------------------------------------------
+    e_loc, off = cfg.n_experts_held, cfg.moe_held_offset
     if ep is not None:
-        shard = jax.lax.axis_index(ep.axis)
-        e_loc = E // ep.n_shards
-        off = shard * e_loc
-        w_gate, w_up, w_down = p["moe_gate"], p["moe_up"], p["moe_down"]
-    else:
-        e_loc, off = E, 0
-        w_gate, w_up, w_down = p["moe_gate"], p["moe_up"], p["moe_down"]
+        e_loc //= ep.n_shards
+        off += jax.lax.axis_index(ep.axis) * e_loc
+    w_gate, w_up, w_down = p["moe_gate"], p["moe_up"], p["moe_down"]
     # Capacity: expected load × factor, floored so tiny decode batches
     # (N ~ a few tokens) stay effectively dropless.
     C = max(math.ceil(k * N / E * cfg.moe_capacity_factor), min(N, 16))
@@ -87,8 +101,8 @@ def moe_block(
     local_e = s_ids - off
     owned = (local_e >= 0) & (local_e < e_loc) & (pos < C)
     slot = jnp.where(owned, local_e * C + pos, e_loc * C)  # OOB -> dropped
-    buf = jnp.zeros((e_loc * C, D), x.dtype).at[slot].set(
-        xt[s_tok] * owned[:, None].astype(x.dtype), mode="drop"
+    buf = jnp.zeros((e_loc * C, D), xt.dtype).at[slot].set(
+        xt[s_tok] * owned[:, None].astype(xt.dtype), mode="drop"
     )
     buf = buf.reshape(e_loc, C, D)
 
@@ -103,11 +117,11 @@ def moe_block(
     # --- combine: gather back, weight, scatter-add over tokens -------------
     contrib = jnp.take(y_buf, jnp.where(owned, slot, e_loc * C), axis=0,
                        mode="fill", fill_value=0.0)
-    contrib = contrib * (s_w * owned)[:, None].astype(x.dtype)
-    out = jnp.zeros((N, D), x.dtype).at[s_tok].add(contrib)
+    contrib = contrib * (s_w * owned)[:, None].astype(xt.dtype)
+    out = jnp.zeros((N, D), xt.dtype).at[s_tok].add(contrib)
     if ep is not None:
         out = jax.lax.psum(out, ep.axis)
-    return out.reshape(B, S, D)
+    return out
 
 
 def moe_aux_loss(

@@ -99,7 +99,7 @@ def test_smoke_configs_are_small():
     for arch in configs.ARCHS:
         cfg = configs.smoke(arch)
         assert cfg.n_params() < 2e6, (arch, cfg.n_params())
-        assert cfg.n_layers == cfg.period * 2
+        assert cfg.n_layers == cfg.first_k_dense + cfg.period * 2
 
 
 def test_shapes_table():
