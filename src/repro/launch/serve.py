@@ -34,11 +34,11 @@ from repro.trace.tracer import Tracer, now_us
 
 # The loop's phases, each a ``repro_serve_phase_us`` series and a
 # ``repro/serve/<phase>`` profiler event: per wave ``prefill`` (its dispatch
-# and argmax), then per decode step ``wait`` (the device finishing the last
-# step and slot 0's slice), ``readback`` (one read per slot), ``dispatch``
-# (the step and its argmax) and ``monitor_step``; per wave ``drain`` and
-# ``ingest``; once ``finish``.
-SERVE_PHASES = ("prefill", "wait", "readback", "dispatch", "monitor_step", "drain",
+# and argmax), then per decode step ``dispatch`` (step n and its argmax),
+# ``wait`` (step n-1 finishing while step n is queued behind it),
+# ``readback`` (step n-1's tokens, one transfer) and ``monitor_step``; per
+# wave ``drain`` and ``ingest``; once ``finish``.
+SERVE_PHASES = ("prefill", "dispatch", "wait", "readback", "monitor_step", "drain",
                 "ingest", "finish")
 
 
@@ -138,19 +138,22 @@ def serve(
                 prompts = np.concatenate([prompts, pad])
             logits, cache = prefill_fn(cparams, {"tokens": jnp.asarray(prompts)})
             next_tok = jnp.argmax(logits[:, -1], axis=-1)
+            next_tok.copy_to_host_async()
         for t in range(max_new):
             with tracer.span("serve/decode_step") as t_entry_us:
-                # Slot 0's slice is dispatched before the wait, as its read
-                # always was, so that it runs as soon as the step is done.
-                with clock.phase("wait"):
-                    head = jax.block_until_ready(next_tok[0])
-                with clock.phase("readback"):
-                    for i, r in enumerate(wave):
-                        r.out.append(int(head if i == 0 else next_tok[i]))
-                    m_syncs.inc(len(wave))
+                # Step t is queued before step t-1's tokens are read, so the
+                # device runs it while the host reads: a wave runs a fixed
+                # max_new steps, and no stop condition needs the tokens first.
                 with clock.phase("dispatch"):
                     logits, cache = decode_fn(cparams, cache, next_tok[:, None].astype(jnp.int32))
-                    next_tok = jnp.argmax(logits[:, 0], axis=-1)
+                    prev_tok, next_tok = next_tok, jnp.argmax(logits[:, 0], axis=-1)
+                    next_tok.copy_to_host_async()
+                with clock.phase("wait"):
+                    jax.block_until_ready(prev_tok)
+                with clock.phase("readback"):
+                    for r, tok in zip(wave, np.asarray(prev_tok).tolist()):
+                        r.out.append(tok)
+                    m_syncs.inc()
                 # The step time the straggler detector judges: this span so far.
                 step_s = (now_us() - t_entry_us) / 1e6
                 with clock.phase("monitor_step"):
