@@ -42,22 +42,6 @@ SERVE_PHASES = ("prefill", "dispatch", "wait", "readback", "monitor_step", "drai
                 "ingest", "finish")
 
 
-# Decode cache entries by kind: MLA's latent, attention's K/V (and their
-# positions), a state-space layer's state.
-_CACHE_KINDS = {"ckv": "latent", "krope": "latent", "k": "kv", "v": "kv", "kpos": "kv",
-                "h": "state", "conv": "state"}
-
-
-def cache_bytes_by_kind(cache) -> Dict[str, int]:
-    """Bytes of a decode cache (arrays or shapes) by kind: latent, kv, state."""
-    out = {kind: 0 for kind in ("latent", "kv", "state")}
-    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
-        kind = _CACHE_KINDS.get(path[-1].key)  # every cache leaf is a dict entry
-        if kind:
-            out[kind] += leaf.size * leaf.dtype.itemsize
-    return out
-
-
 @dataclasses.dataclass
 class Request:
     rid: int
@@ -102,7 +86,7 @@ def serve(
     cache_spec = jax.eval_shape(prefill, cparams, prompt_spec)[1]
     cache_bytes = reg.gauge("repro_serve_cache_bytes",
                             "Bytes of the decode cache serve() holds, by kind.", ("kind",))
-    for kind, nbytes in cache_bytes_by_kind(cache_spec).items():
+    for kind, nbytes in M.cache_bytes_by_kind(cache_spec).items():
         cache_bytes.labels(kind=kind).set(nbytes)
     decode_fn = jax.jit(build_decode_step(cfg, ctx, opts), donate_argnums=(1,)).lower(
         cparams, cache_spec, jax.ShapeDtypeStruct((batch, 1), jnp.int32)

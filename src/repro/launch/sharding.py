@@ -164,8 +164,7 @@ def batch_shardings(cfg: ModelConfig, spec: Dict[str, jax.ShapeDtypeStruct], mes
 def cache_pspec(path_keys, shape, cfg: ModelConfig, mesh) -> P:
     """Decode caches: batch over batch-axes, sequence over the model axis
     (uniform across archs — scales to 500k contexts regardless of head
-    count; attention over the seq-sharded cache is a shard_map flash-decode
-    merge, see models/model.py)."""
+    count; decode attention runs on the sharded cache as XLA partitions it)."""
     tp = tp_size(mesh)
     dpa = mesh_batch_axes(mesh)
     dp = 1

@@ -9,8 +9,8 @@ qwen* and jamba's attention layers.  Two execution paths:
                the pure-XLA analogue of kernels/flash_attention.py, needed so
                32k/500k-token cells compile without materializing S² scores.
 
-The Pallas kernel (kernels/flash_attention.py) replaces the chunked path on
-real TPUs (cfg.use_pallas); both validate against the same oracle in tests.
+kernels/ is not on the model path: no model mode calls its Pallas kernels.
+tests/test_kernels.py checks kernels/flash_attention.py against kernels/ref.py.
 """
 from __future__ import annotations
 
@@ -193,8 +193,9 @@ def attention_partial(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Unnormalized attention over a KV shard: returns (acc, m, l).
 
-    Used by the distributed flash-decode combine (launch/steps.py) and the
-    chunked path below: out = Σ_shards acc·e^{m−m*} / Σ_shards l·e^{m−m*}.
+    Used by GQA decode (models/model.py ``_attn_decode``) and the chunked
+    path below, which combines blocks as
+    out = Σ_blocks acc·e^{m−m*} / Σ_blocks l·e^{m−m*}.
     """
     B, Sq, H, hd = q.shape
     KV = k.shape[2]

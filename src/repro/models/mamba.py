@@ -5,7 +5,7 @@ linear recurrence h_t = a_t h_{t-1} + b_t is evaluated with
 ``lax.associative_scan`` (log-depth, TPU-friendly), chunks are threaded
 sequentially with only the boundary state carried (so backward memory is
 O(S/Lc · B · d_inner · d_state) instead of O(S · ...)).  The Pallas kernel
-(kernels/mamba_scan.py) replaces the inner chunk scan on real TPUs.
+in kernels/mamba_scan.py is not on this path: no model mode calls it.
 
 Decode path: O(1) single-step state update (the reason falcon-mamba/jamba
 run the long_500k cell).
@@ -154,7 +154,7 @@ def mamba_mixer_seq_parallel(
       4. closed-form prefix combine + C_t·A_cum_t·h_in    (local)
 
     Replaces the 2-psum/layer TP formulation whose (B,S,D) fp32 all-reduces
-    dominate falcon-mamba's collective term (EXPERIMENTS.md §Perf)."""
+    dominate falcon-mamba's collective term."""
     import jax.numpy as _jnp
     from jax.sharding import PartitionSpec as P
 

@@ -1,14 +1,20 @@
-"""Unified model assembly: one scan-over-periods stack drives all 10 archs.
+"""Model assembly: every arch is stacks of layer periods, each stack one
+lax.scan over its periods.
 
-Modes:
-  forward / loss_and_metrics  — full-sequence training path
-  prefill                     — sequence pass that also builds the KV/SSM cache
-  decode_step                 — single-token step against the cache
+One layer body (``_layer``) serves every mode; the modes differ only in the
+mixer they hand it:
+
+  forward / loss_and_metrics  — the sequence pass, its scans carrying no cache
+  prefill                     — the same sequence pass, its scans' ys the
+                                layers' decode caches
+  decode_step                 — one token against the cache, through each
+                                mixer's decode form
 
 The cache is stacked over periods per layout position, so decode is also a
 single lax.scan (compile-size friendly at 512 devices).  Ring buffers handle
 SWA windows; MLA caches the compressed latent (its whole point); mamba keeps
-O(1) state.
+O(1) state.  The cache's format lives here alone: ``_layer_cache`` makes its
+leaves and ``cache_bytes_by_kind`` sizes them.
 """
 from __future__ import annotations
 
@@ -23,8 +29,9 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from . import layers as L
-from .common import DENSE, FULL, MAMBA, MLA, MOE, NONE, SWA, LayerSpec, ModelConfig
-from .mamba import init_mamba_state, mamba_decode, mamba_sequence
+from .common import MAMBA, MLA, MOE, NONE, SWA, LayerSpec, ModelConfig
+from .mamba import (_ssm_scan_fused, causal_conv1d, mamba_decode, mamba_mixer_seq_parallel,
+                    mamba_sequence)
 from .moe import EPInfo, moe_block
 
 
@@ -186,7 +193,7 @@ def _attention_seq_parallel(
 
     Replaces XLA's default for unshardable-head archs — contraction
     sharding over head_dim, which all-reduces fp32 (Sq, Sk) score tensors
-    (measured 2–3 GB/layer at train_4k; EXPERIMENTS.md §Perf)."""
+    (2–3 GB/layer at train_4k)."""
     B, S, H, hd = q.shape
     tp = ctx.mesh.shape[ctx.model_axis]
     S_loc = S // tp
@@ -229,7 +236,8 @@ def _use_seq_parallel(ctx: ShardCtx, S: int) -> bool:
     )
 
 
-def _attn_seq(cfg, spec, p, h, cos, sin, ctx: ShardCtx) -> jnp.ndarray:
+def _attn_seq(cfg, spec, p, h, cos, sin, ctx: ShardCtx):
+    """GQA over a sequence: (output, (k, v)), K/V roped, one entry per position."""
     B, S, D = h.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = (h @ p["wq"]).reshape(B, S, H, hd)
@@ -242,7 +250,7 @@ def _attn_seq(cfg, spec, p, h, cos, sin, ctx: ShardCtx) -> jnp.ndarray:
         out = _attention_seq_parallel(
             q, k, v, ctx, causal=cfg.causal, window=window, cap=cfg.attn_softcap
         )
-        return out.reshape(B, S, H * hd) @ p["wo"]
+        return out.reshape(B, S, H * hd) @ p["wo"], (k, v)
     if ctx.mesh is not None and ctx.model_axis and not ctx.seq_shard:
         tp = ctx.mesh.shape[ctx.model_axis]
         b = ctx.batch_axes if ctx.batch_shardable else None
@@ -255,25 +263,12 @@ def _attn_seq(cfg, spec, p, h, cos, sin, ctx: ShardCtx) -> jnp.ndarray:
         q, k, v, causal=cfg.causal, window=window, cap=cfg.attn_softcap,
         direct_threshold=(1 << 30) if ctx.unroll else 1024,
     )
-    return out.reshape(B, S, H * hd) @ p["wo"]
-
-
-def _attn_seq_with_cache(cfg, spec, p, h, cos, sin, ctx):
-    """Prefill: returns (attn_out, (k_full, v_full))."""
-    B, S, D = h.shape
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = (h @ p["wq"]).reshape(B, S, H, hd)
-    k = (h @ p["wk"]).reshape(B, S, KV, hd)
-    v = (h @ p["wv"]).reshape(B, S, KV, hd)
-    if cos is not None:
-        q, k = L.apply_rope(q, cos, sin), L.apply_rope(k, cos, sin)
-    window = cfg.window if spec.mixer == SWA else 0
-    out = L.attention(q, k, v, causal=cfg.causal, window=window, cap=cfg.attn_softcap,
-                      direct_threshold=(1 << 30) if ctx.unroll else 1024)
     return out.reshape(B, S, H * hd) @ p["wo"], (k, v)
 
 
-def _mla_seq(cfg, spec, p, h, cos, sin, ctx, with_cache=False):
+def _mla_seq(cfg, p, h, cos, sin, ctx: ShardCtx):
+    """MLA over a sequence: (output, (latent, roped shared key)), one entry
+    per position."""
     B, S, D = h.shape
     H = cfg.n_heads
     nope, rope, vh = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
@@ -301,10 +296,37 @@ def _mla_seq(cfg, spec, p, h, cos, sin, ctx, with_cache=False):
             out = L.attention(q, k, v, causal=cfg.causal, window=0, cap=cfg.attn_softcap,
                               scale=scale,
                               direct_threshold=(1 << 30) if ctx.unroll else 1024)
-    out = out.reshape(B, S, H * vh) @ p["wo"]
-    if with_cache:
-        return out, (ckv, k_rope[:, :, 0, :])
-    return out
+    return out.reshape(B, S, H * vh) @ p["wo"], (ckv, k_rope[:, :, 0, :])
+
+
+def _seq_mixer(cfg, spec, p, h, cos, sin, ctx: ShardCtx, with_cache: bool):
+    """The mixer of the sequence pass: (output, the layer's decode cache where
+    ``with_cache``, else None)."""
+    S = h.shape[1]
+    if spec.mixer == MAMBA:
+        if _use_seq_parallel(ctx, S):
+            S_loc = S // ctx.mesh.shape[ctx.model_axis]
+            out = mamba_mixer_seq_parallel(
+                p, h, cfg, ctx, chunk=(S_loc if ctx.unroll else min(128, S_loc))
+            )
+        else:
+            out = mamba_sequence(p, h, cfg, chunk=(S if ctx.unroll else 128))
+        # the full-sequence mixer keeps no state: rebuild the final one
+        return out, (_mamba_prefill_state(cfg, p, h) if with_cache else None)
+    if spec.mixer == MLA:
+        out, (ckv, krope) = _mla_seq(cfg, p, h, cos, sin, ctx)
+        return out, ({"ckv": ckv, "krope": krope} if with_cache else None)
+    out, (k, v) = _attn_seq(cfg, spec, p, h, cos, sin, ctx)
+    if not with_cache:
+        return out, None
+    with jax.named_scope("kv_update"):
+        if spec.mixer == SWA:
+            w = min(cfg.window, S)
+            k, v = k[:, -w:], v[:, -w:]
+            kpos = jnp.arange(S - w, S, dtype=jnp.int32)
+        else:
+            kpos = jnp.arange(S, dtype=jnp.int32)
+    return out, {"k": k, "v": v, "kpos": kpos}
 
 
 _MOE_KEYS = ("router", "moe_gate", "moe_up", "moe_down",
@@ -332,38 +354,33 @@ def _mlp_apply(cfg, spec, p, h, ctx: ShardCtx):
         return L.mlp(p, h, cfg.activation)
 
 
-def apply_block(cfg, spec: LayerSpec, p, x, cos, sin, ctx: ShardCtx) -> jnp.ndarray:
-    p = _cast_block_params(p, cfg.compute_dtype)
+def _layer(cfg, spec: LayerSpec, p, x, mix, ctx: ShardCtx, *, constrain: bool = True):
+    """One layer in every mode, on weights in the compute dtype: pre-norm,
+    the mode's mixer ``mix`` (normed hidden states -> (output, the layer's
+    cache)) under ``attention``, sandwich norm where the config has it,
+    residual; then the MLP or MoE the same way.  ``constrain`` pins the
+    residual stream's sharding after each residual, as the sequence pass
+    does; decode leaves it to XLA.  Returns (hidden states, the layer's cache)."""
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-    if spec.mixer == MAMBA:
-        if _use_seq_parallel(ctx, x.shape[1]):
-            from .mamba import mamba_mixer_seq_parallel
-
-            S_loc = x.shape[1] // ctx.mesh.shape[ctx.model_axis]
-            h = mamba_mixer_seq_parallel(
-                p, h, cfg, ctx, chunk=(S_loc if ctx.unroll else min(128, S_loc))
-            )
-        else:
-            h = mamba_sequence(p, h, cfg, chunk=(x.shape[1] if ctx.unroll else 128))
-    elif spec.mixer == MLA:
-        h = _mla_seq(cfg, spec, p, h, cos, sin, ctx)
-    else:
-        h = _attn_seq(cfg, spec, p, h, cos, sin, ctx)
+    with jax.named_scope("attention"):
+        h, cache = mix(h)
     if cfg.sandwich_norm:
         h = L.rms_norm(h, p["post_ln1"], cfg.norm_eps)
     x = x + h
-    x = ctx.constrain(x, ctx.hidden_spec())
+    if constrain:
+        x = ctx.constrain(x, ctx.hidden_spec())
     if spec.mlp != NONE:
         h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
         h = _mlp_apply(cfg, spec, p, h, ctx)
         if cfg.sandwich_norm:
             h = L.rms_norm(h, p["post_ln2"], cfg.norm_eps)
         x = x + h
-        x = ctx.constrain(x, ctx.hidden_spec())
-    return x
+        if constrain:
+            x = ctx.constrain(x, ctx.hidden_spec())
+    return x, cache
 
 
-# ----------------------------------------------------------------- forward
+# ------------------------------------------------------------ sequence pass
 def _stacks(cfg: ModelConfig):
     """(key, layout) of each scanned stack of layers, in order: the leading
     dense layers where the config has them, then the periods.  Params and
@@ -372,36 +389,55 @@ def _stacks(cfg: ModelConfig):
     return lead + [("layers", cfg.layout)]
 
 
-def hidden_states(cfg: ModelConfig, params, batch, ctx: ShardCtx = ShardCtx()) -> jnp.ndarray:
-    x = embed_tokens(cfg, params, batch)
+def _sequence_pass(cfg: ModelConfig, params, batch, ctx: ShardCtx, with_cache: bool,
+                   max_seq: Optional[int] = None):
+    """Embedding, then one scan over each stack's periods: (final hidden
+    states, the decode cache or None).  With ``with_cache`` (static) the
+    scans' ys are the layers' caches, grown to ``max_seq`` decode slots;
+    without it they carry nothing."""
+    with jax.named_scope("embed"):
+        x = embed_tokens(cfg, params, batch)
     B, S, _ = x.shape
     positions = _positions(cfg, batch, B, S)
     cos, sin = _rope_cos_sin(cfg, positions)
     x = ctx.constrain(x, ctx.hidden_spec())
 
+    def layer(p, xc, spec):
+        with jax.named_scope("cast_params"):
+            p = _cast_block_params(p, cfg.compute_dtype)
+        mix = functools.partial(_seq_mixer, cfg, spec, p, cos=cos, sin=sin, ctx=ctx,
+                                with_cache=with_cache)
+        return _layer(cfg, spec, p, xc, mix, ctx)
+
+    cache = {"pos": jnp.asarray(S, jnp.int32)} if with_cache else None
     for key, layout in _stacks(cfg):
         def body(xc, period_params, layout=layout):
-            for pos, spec in enumerate(layout):
+            caches = []
+            for i, spec in enumerate(layout):
+                f = functools.partial(layer, spec=spec)
                 if ctx.remat == "block" and len(layout) > 1:
                     # nested remat: multi-layer periods (jamba: 8 layers) would
                     # otherwise hold the whole period's intermediates in the
                     # backward working set (measured 25 GiB of temps at 52B)
-                    blk = jax.checkpoint(
-                        lambda pp, xx, s=spec: apply_block(cfg, s, pp, xx, cos, sin, ctx)
-                    )
-                    xc = blk(period_params[pos], xc)
-                else:
-                    xc = apply_block(cfg, spec, period_params[pos], xc, cos, sin, ctx)
-            return xc, None
+                    f = jax.checkpoint(f)
+                xc, c = f(period_params[i], xc)
+                caches.append(c)
+            return xc, caches
 
         if ctx.remat == "block":
             body = jax.checkpoint(body)
-        x, _ = jax.lax.scan(body, x, params[key], unroll=ctx.scan_unroll)
-    return x
+        x, caches = jax.lax.scan(body, x, params[key], unroll=ctx.scan_unroll)
+        if with_cache:
+            if max_seq is not None and max_seq != S:
+                with jax.named_scope("kv_update"):
+                    caches = _expand_prefill_cache(cfg, layout, caches, S, max_seq)
+            cache[key] = caches
+    return x, cache
 
 
 def forward(cfg: ModelConfig, params, batch, ctx: ShardCtx = ShardCtx()) -> jnp.ndarray:
-    logits = unembed(cfg, params, hidden_states(cfg, params, batch, ctx))
+    x, _ = _sequence_pass(cfg, params, batch, ctx, with_cache=False)
+    logits = unembed(cfg, params, x)
     return logits[..., : cfg.vocab]  # crop padding (API surface only)
 
 
@@ -415,7 +451,7 @@ def loss_and_metrics(
     live logits at (B, chunk, V); the backward pass recomputes each chunk's
     logits from the final hidden states.
     """
-    x = hidden_states(cfg, params, batch, ctx)
+    x, _ = _sequence_pass(cfg, params, batch, ctx, with_cache=False)
     B, S, _ = x.shape
     labels = batch["labels"]
     cs = min(ce_chunk, S)
@@ -444,6 +480,18 @@ def loss_and_metrics(
     return loss, {"loss": loss, "accuracy": hits / jnp.maximum(msum, 1.0), "tokens": msum}
 
 
+def prefill(
+    cfg: ModelConfig, params, batch, ctx: ShardCtx = ShardCtx(),
+    max_seq: Optional[int] = None,
+):
+    """Sequence pass returning (last-position logits, populated cache), its
+    ops scoped like ``decode_step``'s."""
+    x, cache = _sequence_pass(cfg, params, batch, ctx, with_cache=True, max_seq=max_seq)
+    with jax.named_scope("lm_head"):
+        logits = unembed(cfg, params, x[:, -1:])
+    return logits, cache
+
+
 # ------------------------------------------------------------------- cache
 def _layer_cache(cfg: ModelConfig, spec: LayerSpec, n: int, batch: int, max_seq: int):
     """Zeroed decode cache of one layout position, stacked over ``n`` layers."""
@@ -464,6 +512,22 @@ def _layer_cache(cfg: ModelConfig, spec: LayerSpec, n: int, batch: int, max_seq:
         "v": jnp.zeros((n, batch, Sc, cfg.n_kv_heads, cfg.head_dim), dt),
         "kpos": jnp.full((n, Sc), -1, jnp.int32),
     }
+
+
+# The leaves ``_layer_cache`` makes, by kind: MLA's latent, attention's K/V
+# (and their positions), a state-space layer's state.
+_CACHE_KINDS = {"ckv": "latent", "krope": "latent", "k": "kv", "v": "kv", "kpos": "kv",
+                "h": "state", "conv": "state"}
+
+
+def cache_bytes_by_kind(cache) -> Dict[str, int]:
+    """Bytes of a decode cache (arrays or shapes) by kind: latent, kv, state."""
+    out = {kind: 0 for kind in ("latent", "kv", "state")}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
+        kind = _CACHE_KINDS.get(path[-1].key)  # every cache leaf is a dict entry
+        if kind:
+            out[kind] += leaf.size * leaf.dtype.itemsize
+    return out
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> Dict[str, Any]:
@@ -500,136 +564,6 @@ def _attn_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx):
     out = acc / jnp.maximum(l, 1e-30)[..., None]  # (B,KV,G,1,hd)
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, 1, H * hd).astype(h.dtype)
     return out @ p["wo"], {"k": ck, "v": cv, "kpos": kpos}
-
-
-def _seq_sharded(ctx: ShardCtx, Sc: int) -> bool:
-    """Is the decode cache sequence-sharded over the model axis?"""
-    return (
-        ctx.mesh is not None
-        and ctx.model_axis in getattr(ctx.mesh, "axis_names", ())
-        and Sc % ctx.mesh.shape[ctx.model_axis] == 0
-        and Sc >= ctx.mesh.shape[ctx.model_axis]
-    )
-
-
-def _attn_decode_sharded(cfg, spec, p, q, k_new, v_new, cache, pos, ctx):
-    """Flash-decode over a sequence-sharded KV cache: every model shard
-    attends to its cache slice, partial softmaxes merge with one
-    pmax + two psums (the same merge pattern as the Chimbuko PS merge)."""
-    B = q.shape[0]
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    window = cfg.window if spec.mixer == SWA else 0
-    Sc = cache["k"].shape[1]
-    slot = pos % Sc
-    b = ctx.batch_axes if ctx.batch_shardable else None
-    m_ax = ctx.model_axis
-
-    def f(qr, knr, vnr, kc, vc, kposc, slotr, posr):
-        i = jax.lax.axis_index(m_ax)
-        Sc_loc = kc.shape[1]
-        rel = slotr - i * Sc_loc
-        owned = (rel >= 0) & (rel < Sc_loc)
-        relc = jnp.clip(rel, 0, Sc_loc - 1)
-        old_k = jax.lax.dynamic_index_in_dim(kc, relc, 1, keepdims=False)
-        old_v = jax.lax.dynamic_index_in_dim(vc, relc, 1, keepdims=False)
-        kc = jax.lax.dynamic_update_index_in_dim(
-            kc, jnp.where(owned, knr[:, 0], old_k), relc, axis=1
-        )
-        vc = jax.lax.dynamic_update_index_in_dim(
-            vc, jnp.where(owned, vnr[:, 0], old_v), relc, axis=1
-        )
-        kposc = jax.lax.dynamic_update_index_in_dim(
-            kposc, jnp.where(owned, posr, kposc[relc]), relc, axis=0
-        )
-        acc, m, l = L.attention_partial(
-            qr, kc, vc, causal=True, window=window, cap=cfg.attn_softcap,
-            scale=1.0 / math.sqrt(hd),
-            qpos=jnp.full((1, 1), posr), kpos=kposc[None, :],
-        )
-        m_g = jax.lax.pmax(m, m_ax)
-        r = jnp.exp(m - m_g)
-        l_g = jax.lax.psum(l * r, m_ax)
-        acc_g = jax.lax.psum(acc * r[..., None], m_ax)
-        out = acc_g / jnp.maximum(l_g, 1e-30)[..., None]
-        out = out.transpose(0, 3, 1, 2, 4).reshape(B // (1 if b is None else _prod(ctx.mesh, b)), 1, H * hd)
-        return out.astype(qr.dtype), kc, vc, kposc
-
-    fn = shard_map(
-        f,
-        mesh=ctx.mesh,
-        in_specs=(
-            P(b, None, None, None), P(b, None, None, None), P(b, None, None, None),
-            P(b, m_ax, None, None), P(b, m_ax, None, None), P(m_ax), P(), P(),
-        ),
-        out_specs=(
-            P(b, None, None), P(b, m_ax, None, None), P(b, m_ax, None, None), P(m_ax),
-        ),
-    )
-    out, ck, cv, kpos = fn(q, k_new, v_new, cache["k"], cache["v"], cache["kpos"], slot, pos)
-    return out, {"k": ck, "v": cv, "kpos": kpos}
-
-
-def _prod(mesh, axes) -> int:
-    n = 1
-    for a in axes:
-        n *= mesh.shape[a]
-    return n
-
-
-def _mla_decode_sharded(cfg, p, q_eff, q_rope, ckv_new, krope_new, cache, pos, ctx):
-    """Absorbed-MLA flash-decode over the sequence-sharded latent cache."""
-    B = q_eff.shape[0]
-    H = cfg.n_heads
-    kvr, vh = cfg.kv_lora_rank, cfg.v_head_dim
-    b = ctx.batch_axes if ctx.batch_shardable else None
-    m_ax = ctx.model_axis
-    scale = _mla_scale(cfg)
-
-    def f(qe, qr_, cn, kn, cc, kc, posr):
-        i = jax.lax.axis_index(m_ax)
-        Sc_loc = cc.shape[1]
-        rel = posr - i * Sc_loc  # MLA slots == positions (no ring)
-        owned = (rel >= 0) & (rel < Sc_loc)
-        relc = jnp.clip(rel, 0, Sc_loc - 1)
-        old_c = jax.lax.dynamic_index_in_dim(cc, relc, 1, keepdims=False)
-        old_k = jax.lax.dynamic_index_in_dim(kc, relc, 1, keepdims=False)
-        cc = jax.lax.dynamic_update_index_in_dim(
-            cc, jnp.where(owned, cn[:, 0], old_c), relc, axis=1
-        )
-        kc = jax.lax.dynamic_update_index_in_dim(
-            kc, jnp.where(owned, kn[:, 0, 0], old_k), relc, axis=1
-        )
-        s = jnp.einsum("bqhk,bsk->bhqs", qe.astype(jnp.float32), cc.astype(jnp.float32))
-        s += jnp.einsum("bqhr,bsr->bhqs", qr_.astype(jnp.float32), kc.astype(jnp.float32))
-        s *= scale
-        s = L.softcap(s, cfg.attn_softcap)
-        valid = (i * Sc_loc + jnp.arange(Sc_loc))[None, None, None, :] <= posr
-        s = jnp.where(valid, s, L.NEG_INF)
-        m = s.max(-1)
-        pvals = jnp.where((m <= L.NEG_INF / 2)[..., None], 0.0, jnp.exp(s - m[..., None]))
-        l = pvals.sum(-1)
-        acc = jnp.einsum("bhqs,bsk->bhqk", pvals, cc.astype(jnp.float32))
-        m_g = jax.lax.pmax(m, m_ax)
-        r = jnp.exp(m - m_g)
-        l_g = jax.lax.psum(l * r, m_ax)
-        acc_g = jax.lax.psum(acc * r[..., None], m_ax)
-        lat = (acc_g / jnp.maximum(l_g, 1e-30)[..., None]).transpose(0, 2, 1, 3)
-        return lat, cc, kc
-
-    fn = shard_map(
-        f,
-        mesh=ctx.mesh,
-        in_specs=(
-            P(b, None, None, None), P(b, None, None, None),
-            P(b, None, None), P(b, None, None, None),
-            P(b, m_ax, None), P(b, m_ax, None), P(),
-        ),
-        out_specs=(P(b, None, None, None), P(b, m_ax, None), P(b, m_ax, None)),
-    )
-    lat, ckv, krope = fn(
-        q_eff, q_rope, ckv_new, krope_new, cache["ckv"], cache["krope"], pos
-    )
-    return lat, {"ckv": ckv, "krope": krope}
 
 
 def _mla_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx):
@@ -671,26 +605,13 @@ def _mla_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx):
     return out @ p["wo"], {"ckv": ckv, "krope": krope}
 
 
-def decode_block(cfg, spec, p, x, cache, pos, cos, sin, ctx):
-    """One layer of a decode step, on weights already cast to the compute dtype."""
-    h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-    with jax.named_scope("attention"):
-        if spec.mixer == MAMBA:
-            h, new_cache = mamba_decode(p, h, cache, cfg)
-        elif spec.mixer == MLA:
-            h, new_cache = _mla_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx)
-        else:
-            h, new_cache = _attn_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx)
-    if cfg.sandwich_norm:
-        h = L.rms_norm(h, p["post_ln1"], cfg.norm_eps)
-    x = x + h
-    if spec.mlp != NONE:
-        h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-        h = _mlp_apply(cfg, spec, p, h, ctx)
-        if cfg.sandwich_norm:
-            h = L.rms_norm(h, p["post_ln2"], cfg.norm_eps)
-        x = x + h
-    return x, new_cache
+def _decode_mixer(cfg, spec, p, h, cache, pos, cos, sin, ctx):
+    """The mixer of a decode step: (output, the layer's updated cache)."""
+    if spec.mixer == MAMBA:
+        return mamba_decode(p, h, cache, cfg)
+    if spec.mixer == MLA:
+        return _mla_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx)
+    return _attn_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx)
 
 
 def decode_step(
@@ -724,9 +645,10 @@ def decode_step(
             period_params, period_cache = slices
             new_caches = []
             for i, spec in enumerate(layout):
-                xc, nc = decode_block(
-                    cfg, spec, period_params[i], xc, period_cache[i], pos, cos, sin, ctx
-                )
+                mix = functools.partial(_decode_mixer, cfg, spec, period_params[i],
+                                        cache=period_cache[i], pos=pos, cos=cos, sin=sin,
+                                        ctx=ctx)
+                xc, nc = _layer(cfg, spec, period_params[i], xc, mix, ctx, constrain=False)
                 new_caches.append(nc)
             return xc, new_caches
 
@@ -779,81 +701,9 @@ def _expand_prefill_cache(cfg: ModelConfig, layout, layer_caches, S: int, max_se
     return out
 
 
-def _prefill_block(cfg, spec, p, xc, cos, sin, ctx):
-    """One layer of a prefill: (new hidden states, the layer's cache)."""
-    S = xc.shape[1]
-    with jax.named_scope("cast_params"):
-        p = _cast_block_params(p, cfg.compute_dtype)
-    h = L.rms_norm(xc, p["ln1"], cfg.norm_eps)
-    with jax.named_scope("attention"):
-        if spec.mixer == MAMBA:
-            # full-sequence mixer; rebuild final state for the cache
-            hh = mamba_sequence(p, h, cfg, chunk=(h.shape[1] if ctx.unroll else 128))
-            cch = _mamba_prefill_state(cfg, p, h)
-            h = hh
-        elif spec.mixer == MLA:
-            h, (ckv, krope) = _mla_seq(cfg, spec, p, h, cos, sin, ctx, with_cache=True)
-            cch = {"ckv": ckv, "krope": krope}
-        else:
-            h, (k, v) = _attn_seq_with_cache(cfg, spec, p, h, cos, sin, ctx)
-            with jax.named_scope("kv_update"):
-                if spec.mixer == SWA:
-                    w = min(cfg.window, S)
-                    k, v = k[:, -w:], v[:, -w:]
-                    kpos = jnp.arange(S - w, S, dtype=jnp.int32)
-                else:
-                    kpos = jnp.arange(S, dtype=jnp.int32)
-                cch = {"k": k, "v": v, "kpos": kpos}
-    if cfg.sandwich_norm:
-        h = L.rms_norm(h, p["post_ln1"], cfg.norm_eps)
-    xc = xc + h
-    if spec.mlp != NONE:
-        h = L.rms_norm(xc, p["ln2"], cfg.norm_eps)
-        h = _mlp_apply(cfg, spec, p, h, ctx)
-        if cfg.sandwich_norm:
-            h = L.rms_norm(h, p["post_ln2"], cfg.norm_eps)
-        xc = xc + h
-    return ctx.constrain(xc, ctx.hidden_spec()), cch
-
-
-def prefill(
-    cfg: ModelConfig, params, batch, ctx: ShardCtx = ShardCtx(),
-    max_seq: Optional[int] = None,
-):
-    """Sequence pass returning (last-position logits, populated cache), its
-    ops scoped like ``decode_step``'s."""
-    with jax.named_scope("embed"):
-        x = embed_tokens(cfg, params, batch)
-    B, S, _ = x.shape
-    positions = _positions(cfg, batch, B, S)
-    cos, sin = _rope_cos_sin(cfg, positions)
-    x = ctx.constrain(x, ctx.hidden_spec())
-
-    out = {"pos": jnp.asarray(S, jnp.int32)}
-    for key, layout in _stacks(cfg):
-        def body(xc, period_params, layout=layout):
-            caches = []
-            for i, spec in enumerate(layout):
-                xc, cch = _prefill_block(cfg, spec, period_params[i], xc, cos, sin, ctx)
-                caches.append(cch)
-            return xc, caches
-
-        if ctx.remat == "block":
-            body = jax.checkpoint(body)
-        x, out[key] = jax.lax.scan(body, x, params[key], unroll=ctx.scan_unroll)
-        if max_seq is not None and max_seq != S:
-            with jax.named_scope("kv_update"):
-                out[key] = _expand_prefill_cache(cfg, layout, out[key], S, max_seq)
-    with jax.named_scope("lm_head"):
-        logits = unembed(cfg, params, x[:, -1:])
-    return logits, out
-
-
 def _mamba_prefill_state(cfg, p, u):
     """Final (h, conv) state after a full sequence (for prefill->decode)."""
     di, st, dr = cfg.d_inner, cfg.ssm_d_state, cfg.dt_rank
-    from .mamba import causal_conv1d, _ssm_scan_fused
-
     xz = u @ p["in_proj"]
     x, _ = jnp.split(xz, 2, axis=-1)
     conv_tail = x[:, -(cfg.ssm_d_conv - 1) :, :]

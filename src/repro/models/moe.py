@@ -1,6 +1,6 @@
 """Mixture-of-Experts with TPU-native expert parallelism.
 
-Design (DESIGN.md §6): tokens are sharded over the batch axes and
+Design: tokens are sharded over the batch axes and
 *replicated* over the model axis; experts are sharded over the model axis.
 Every (data, model) device therefore already holds the tokens its experts
 need — dispatch is local (sort-based, capacity-bounded) and the ONLY

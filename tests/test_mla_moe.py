@@ -232,7 +232,7 @@ def test_programs_carry_the_mla_and_moe_scopes():
 def test_serve_reports_latent_cache_bytes_and_makes_no_weight_copy():
     """serve() sets repro_serve_cache_bytes from the cache's shapes, and on
     weights already in the compute dtype copies nothing."""
-    from repro.launch.serve import cache_bytes_by_kind, serve
+    from repro.launch.serve import serve
     from repro.telemetry.registry import get_registry
 
     cfg = configs.smoke("deepseek_v2_lite_ep8")
@@ -246,5 +246,5 @@ def test_serve_reports_latent_cache_bytes_and_makes_no_weight_copy():
     assert series == {"latent": latent, "kv": 0, "state": 0}
     assert snap["repro_serve_compute_param_bytes"]["series"]["[]"] == 0
     granite = configs.smoke("granite_moe_1b_a400m")
-    kinds = cache_bytes_by_kind(jax.eval_shape(lambda: M.init_cache(granite, 2, 10)))
+    kinds = M.cache_bytes_by_kind(jax.eval_shape(lambda: M.init_cache(granite, 2, 10)))
     assert kinds["latent"] == 0 and kinds["kv"] > 0

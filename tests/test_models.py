@@ -39,7 +39,7 @@ def test_smoke_forward_and_train_step(arch):
 @pytest.mark.parametrize(
     "arch",
     ["gemma2-2b", "minicpm3-4b", "falcon-mamba-7b", "jamba-v0.1-52b", "qwen2-vl-2b",
-     "h2o-danube-3-4b", "granite-moe-1b-a400m"],
+     "h2o-danube-3-4b", "granite-moe-1b-a400m", "deepseek-v2-lite", "deepseek-v2-lite-ep8"],
 )
 def test_decode_matches_forward(arch):
     """prefill(S-1) + decode(last) must equal full forward's last logits.
