@@ -8,7 +8,9 @@ mixer they hand it:
   prefill                     — the same sequence pass, its scans' ys the
                                 layers' decode caches
   decode_step                 — one token against the cache, through each
-                                mixer's decode form
+                                mixer's decode form; the scan reads the
+                                cache, and one row per layer is written
+                                after it
 
 The cache is stacked over periods per layout position, so decode is also a
 single lax.scan (compile-size friendly at 512 devices).  Ring buffers handle
@@ -518,6 +520,9 @@ def _layer_cache(cfg: ModelConfig, spec: LayerSpec, n: int, batch: int, max_seq:
 # (and their positions), a state-space layer's state.
 _CACHE_KINDS = {"ckv": "latent", "krope": "latent", "k": "kv", "v": "kv", "kpos": "kv",
                 "h": "state", "conv": "state"}
+# The sequence axis of each positional (latent, kv) leaf, stacked over layers:
+# where a decode step writes its row.
+_ROW_AXIS = {"ckv": 2, "krope": 2, "k": 2, "v": 2, "kpos": 1}
 
 
 def cache_bytes_by_kind(cache) -> Dict[str, int]:
@@ -541,7 +546,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> Dict[str, Any]:
 
 
 def _attn_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx):
-    """One-token attention against (possibly ring-buffered) cache."""
+    """One-token attention against the layer's (possibly ring-buffered)
+    cache slice, read in place: the slot the new row takes is masked, a
+    ring's stale entry with it, and the new k/v, rounded to the cache dtype,
+    are a second block combined by the online-softmax rule.  Returns
+    (output, the layer's new row of each cache leaf)."""
     B = h.shape[0]
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = (h @ p["wq"]).reshape(B, 1, H, hd)
@@ -551,23 +560,30 @@ def _attn_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx):
         q, k = L.apply_rope(q, cos, sin), L.apply_rope(k, cos, sin)
     Sc = cache["k"].shape[1]  # cache slice inside scan: (B, Sc, KV, hd)
     slot = pos % Sc  # ring for SWA; plain index otherwise (pos < Sc)
-    with jax.named_scope("kv_update"):
-        ck = jax.lax.dynamic_update_index_in_dim(cache["k"], k[:, 0], slot, axis=1)
-        cv = jax.lax.dynamic_update_index_in_dim(cache["v"], v[:, 0], slot, axis=1)
-        kpos = jax.lax.dynamic_update_index_in_dim(cache["kpos"], pos, slot, axis=0)
-    window = cfg.window if spec.mixer == SWA else 0
-    acc, m, l = L.attention_partial(
-        q, ck, cv, causal=True, window=window, cap=cfg.attn_softcap,
-        scale=1.0 / math.sqrt(hd),
-        qpos=jnp.full((1, 1), pos), kpos=kpos[None, :],
+    row = {"k": k[:, 0].astype(cache["k"].dtype), "v": v[:, 0].astype(cache["v"].dtype),
+           "kpos": pos}
+    kpos = jnp.where(jnp.arange(Sc) == slot, -1, cache["kpos"])
+    attend = functools.partial(
+        L.attention_partial, q, causal=True, window=cfg.window if spec.mixer == SWA else 0,
+        cap=cfg.attn_softcap, scale=1.0 / math.sqrt(hd), qpos=jnp.full((1, 1), pos),
     )
+    acc, m, l = attend(cache["k"], cache["v"], kpos=kpos[None, :])
+    acc_new, m_new, l_new = attend(row["k"][:, None], row["v"][:, None],
+                                   kpos=jnp.full((1, 1), pos))
+    m_all = jnp.maximum(m, m_new)
+    r, r_new = jnp.exp(m - m_all), jnp.exp(m_new - m_all)
+    acc = acc * r[..., None] + acc_new * r_new[..., None]
+    l = l * r + l_new * r_new
     out = acc / jnp.maximum(l, 1e-30)[..., None]  # (B,KV,G,1,hd)
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, 1, H * hd).astype(h.dtype)
-    return out @ p["wo"], {"k": ck, "v": cv, "kpos": kpos}
+    return out @ p["wo"], row
 
 
 def _mla_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx):
-    """Absorbed-matrix MLA decode on the compressed latent cache."""
+    """Absorbed-matrix MLA decode on the compressed latent cache, read in
+    place: slots at and past ``pos`` are masked, and the new latent and
+    roped key, rounded to the cache dtype, join as one more score and value
+    term.  Returns (output, the layer's new row of each cache leaf)."""
     B = h.shape[0]
     H = cfg.n_heads
     nope, rope, vh, kvr = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim, cfg.kv_lora_rank
@@ -579,34 +595,37 @@ def _mla_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx):
     if cos is not None:
         q_rope = L.apply_rope(q_rope, cos, sin)
         krope_new = L.apply_rope(krope_new, cos, sin)
-    with jax.named_scope("kv_update"):
-        ckv = jax.lax.dynamic_update_index_in_dim(cache["ckv"], ckv_new[:, 0], pos, axis=1)
-        krope = jax.lax.dynamic_update_index_in_dim(
-            cache["krope"], krope_new[:, 0, 0], pos, axis=1
-        )
+    row = {"ckv": ckv_new[:, 0].astype(cache["ckv"].dtype),
+           "krope": krope_new[:, 0, 0].astype(cache["krope"].dtype)}
     # absorb W_uk into q:  q_eff (B,1,H,kvr)
     wuk = p["wuk"].reshape(kvr, H, nope)
     q_eff = jnp.einsum("bqhn,khn->bqhk", q_nope, wuk)
     # The latent part: scores, softmax and values over the latent cache.
     with jax.named_scope("latent_attention"):
-        scores = jnp.einsum("bqhk,bsk->bhqs", q_eff.astype(jnp.float32), ckv.astype(jnp.float32))
-        scores += jnp.einsum(
-            "bqhr,bsr->bhqs", q_rope.astype(jnp.float32), krope.astype(jnp.float32)
-        )
+        q_eff, q_rope = q_eff.astype(jnp.float32), q_rope.astype(jnp.float32)
+        ckv = cache["ckv"].astype(jnp.float32)
+        ckv_row = row["ckv"].astype(jnp.float32)
+        scores = jnp.einsum("bqhk,bsk->bhqs", q_eff, ckv)
+        scores += jnp.einsum("bqhr,bsr->bhqs", q_rope, cache["krope"].astype(jnp.float32))
+        score_new = jnp.einsum("bqhk,bk->bhq", q_eff, ckv_row)
+        score_new += jnp.einsum("bqhr,br->bhq", q_rope, row["krope"].astype(jnp.float32))
+        scores = jnp.concatenate([scores, score_new[..., None]], axis=-1)
         scores *= _mla_scale(cfg)
         scores = L.softcap(scores, cfg.attn_softcap)
         Sc = ckv.shape[1]
-        valid = jnp.arange(Sc)[None, None, None, :] <= pos
-        scores = jnp.where(valid, scores, L.NEG_INF)
+        s = jnp.arange(Sc + 1)[None, None, None, :]
+        scores = jnp.where((s < pos) | (s == Sc), scores, L.NEG_INF)
         probs = jax.nn.softmax(scores, axis=-1)
-        lat = jnp.einsum("bhqs,bsk->bqhk", probs, ckv.astype(jnp.float32))  # (B,1,H,kvr)
+        lat = jnp.einsum("bhqs,bsk->bqhk", probs[..., :Sc], ckv)  # (B,1,H,kvr)
+        lat += jnp.einsum("bhq,bk->bqhk", probs[..., Sc], ckv_row)
     wuv = p["wuv"].reshape(kvr, H, vh)
     out = jnp.einsum("bqhk,khv->bqhv", lat, wuv).reshape(B, 1, H * vh).astype(h.dtype)
-    return out @ p["wo"], {"ckv": ckv, "krope": krope}
+    return out @ p["wo"], row
 
 
 def _decode_mixer(cfg, spec, p, h, cache, pos, cos, sin, ctx):
-    """The mixer of a decode step: (output, the layer's updated cache)."""
+    """The mixer of a decode step: (output, the layer's new cache rows, or
+    a state layer's whole new state)."""
     if spec.mixer == MAMBA:
         return mamba_decode(p, h, cache, cfg)
     if spec.mixer == MLA:
@@ -643,19 +662,38 @@ def decode_step(
 
         def body(xc, slices, layout=layout):
             period_params, period_cache = slices
-            new_caches = []
+            rows = []
             for i, spec in enumerate(layout):
                 mix = functools.partial(_decode_mixer, cfg, spec, period_params[i],
                                         cache=period_cache[i], pos=pos, cos=cos, sin=sin,
                                         ctx=ctx)
-                xc, nc = _layer(cfg, spec, period_params[i], xc, mix, ctx, constrain=False)
-                new_caches.append(nc)
-            return xc, new_caches
+                xc, r = _layer(cfg, spec, period_params[i], xc, mix, ctx, constrain=False)
+                rows.append(r)
+            return xc, rows
 
-        x, new[key] = jax.lax.scan(body, x, (layers, cache[key]), unroll=ctx.scan_unroll)
+        # The scan reads the cache and returns rows: written inside it, the
+        # stack or each layer's slice would be copied whole every step.
+        x, rows = jax.lax.scan(body, x, (layers, cache[key]), unroll=ctx.scan_unroll)
+        with jax.named_scope("kv_update"):
+            new[key] = [_write_rows(c, r, pos) for c, r in zip(cache[key], rows)]
     with jax.named_scope("lm_head"):
         logits = unembed(cfg, params, x)
     return logits, new
+
+
+def _write_rows(layer_cache, rows, pos):
+    """A layout position's cache after a decode step, from its layer scan's
+    ys: a positional leaf (latent, kv) gets every layer's new row written in
+    place at slot ``pos % Sc``; a state leaf is the scan's whole new state."""
+    out = {}
+    for name, leaf in layer_cache.items():
+        if _CACHE_KINDS[name] == "state":
+            out[name] = rows[name]
+            continue
+        axis = _ROW_AXIS[name]
+        out[name] = jax.lax.dynamic_update_index_in_dim(leaf, rows[name],
+                                                        pos % leaf.shape[axis], axis)
+    return out
 
 
 def _expand_prefill_cache(cfg: ModelConfig, layout, layer_caches, S: int, max_seq: int):

@@ -117,6 +117,38 @@ def test_swa_ring_buffer_consistency():
     np.testing.assert_allclose(dec, np.asarray(full, np.float32), rtol=1e-4, atol=1e-3)
 
 
+@pytest.mark.parametrize("arch,window,S", [("granite-moe-1b-a400m", 0, 8),
+                                            ("h2o-danube-3-4b", 8, 12),  # past the wrap
+                                            ("minicpm3-4b", 0, 8)])
+def test_decode_never_reads_the_slot_it_writes(arch, window, S):
+    """The cache slot a decode step writes (``pos``; ``pos % window`` in a
+    ring) is never read as a key: filled with large garbage and an
+    attendable position, it gives the same logits and new cache, bit for
+    bit, as a zeroed, unwritten slot."""
+    cfg = dataclasses.replace(configs.smoke(arch), compute_dtype=jnp.float32)
+    if window:
+        cfg = dataclasses.replace(cfg, window=window)
+    B, pos = 2, S - 1
+    params = init_params(cfg, jax.random.key(6))
+    tokens = synthetic_batch(cfg, B, S, seed=6)["tokens"]
+    _, cache = M.prefill(cfg, params, {"tokens": tokens[:, :pos]}, max_seq=16)
+
+    def fill(value, kpos):
+        def one(path, leaf):
+            name = path[-1].key
+            if name == "pos":
+                return leaf
+            axis = M._ROW_AXIS[name]
+            at = [slice(None)] * leaf.ndim
+            at[axis] = pos % leaf.shape[axis]
+            return leaf.at[tuple(at)].set(kpos if name == "kpos" else value)
+        return jax.tree_util.tree_map_with_path(one, cache)
+
+    got = M.decode_step(cfg, params, fill(1e4, pos - 1), tokens[:, pos:])
+    want = M.decode_step(cfg, params, fill(0.0, -1), tokens[:, pos:])
+    jax.tree.map(np.testing.assert_array_equal, got, want)
+
+
 def test_moe_matches_dense_routing_reference():
     """Sort-based capacity dispatch == naive per-token loop (ample capacity)."""
     cfg = dataclasses.replace(

@@ -58,16 +58,17 @@ def test_moments_kernel_compiles_for_v5e(one_chip, num_funcs, num_events):
     assert labels.shape == (num_events,) and labels.dtype == jnp.int8
 
 
-def _decode_step_for_v5e(one_chip, params_of):
-    """The served decode step at published widths, compiled for a described
-    v5e on ``params_of(cfg, params)``: (cfg, HLO text)."""
+def _compile_decode_step_for_v5e(one_chip, arch, prompt, params_of=lambda cfg, p: p):
+    """The served decode step of ``arch`` at published widths (batch 8,
+    ``prompt`` + 128 cache slots), compiled for a described v5e on
+    ``params_of(cfg, params)``: (cfg, the cache's shapes, HLO text)."""
     from repro import configs
     from repro.launch.steps import (StepOptions, build_decode_step, build_prefill_step,
                                     make_shard_ctx)
     from repro.models.common import init_params
 
-    cfg = configs.get_config("granite_moe_1b_a400m")
-    batch, prompt = 8, 1024
+    cfg = configs.get_config(arch)
+    batch = 8
     opts = StepOptions()
     ctx = make_shard_ctx(cfg, None, batch, opts)
 
@@ -83,8 +84,74 @@ def _decode_step_for_v5e(one_chip, params_of):
     tokens = on_chip(jax.ShapeDtypeStruct((batch, 1), jnp.int32))
     text = jax.jit(build_decode_step(cfg, ctx, opts), donate_argnums=(1,)).lower(
         params, cache, tokens).compile().as_text()
+    return cfg, cache, text
+
+
+def _decode_step_for_v5e(one_chip, params_of):
+    """The lines of granite's compiled decode step (1,024-token prompts) that
+    convert the stacked expert weights to bfloat16."""
+    cfg, _, text = _compile_decode_step_for_v5e(one_chip, "granite_moe_1b_a400m", 1024,
+                                                params_of)
     expert = re.compile(rf"= bf16\[{cfg.n_layers},{cfg.moe_experts},\d+,\d+\]\S* convert\(")
     return [line for line in text.splitlines() if expert.search(line)]
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def _top_level_ops(text):
+    """(name, shape, op) of every array-valued instruction of the entry
+    computation and of the loop bodies it runs; fused computations are left
+    out, since their ops write no buffer of their own."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = "ENTRY" if line.startswith("ENTRY") else m.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    todo, seen, ops = ["ENTRY"], set(), []
+    while todo:
+        comp = todo.pop()
+        seen.add(comp)
+        for line in comps[comp]:
+            todo += [b for b in re.findall(r"body=%([\w.-]+)", line) if b not in seen]
+            m = _INSTRUCTION.match(line)
+            if m:
+                ops.append((m.group(1), f"{m.group(2)}[{m.group(3)}]", m.group(4)))
+    return ops
+
+
+@pytest.mark.parametrize("arch,prompt", [("granite_moe_1b_a400m", 1024),
+                                         ("deepseek_v2_lite_ep8", 16384)])
+def test_decode_step_writes_cache_rows_in_place_for_v5e(one_chip, arch, prompt):
+    """At published widths, the decode step's program keeps every cache
+    leaf aliased input to output, and neither its entry computation nor its
+    layer loop copies or fuses out a positional cache leaf whole or one
+    layer's (batch, slots, ...) slice of it: the cache is read in place and
+    its only writes are the one-row ``dynamic-update-slice``s."""
+    from repro.models.model import _CACHE_KINDS
+
+    cfg, cache, text = _compile_decode_step_for_v5e(one_chip, arch, prompt)
+    leaves = jax.tree_util.tree_leaves_with_path(cache)
+    # outputs: the logits, then the cache's leaves in order
+    aliased = {int(i) for i in re.findall(r"\{(\d+)\}: \(\d+,", text.splitlines()[0])}
+    assert aliased == set(range(1, len(leaves) + 1)), aliased
+    positional = [leaf for path, leaf in leaves
+                  if _CACHE_KINDS.get(path[-1].key) in ("latent", "kv")]
+    assert positional
+    dt = {"bfloat16": "bf16", "int32": "s32"}
+
+    def hlo(leaf, shape):
+        return f"{dt[leaf.dtype.name]}[{','.join(map(str, shape))}]"
+
+    whole = {hlo(leaf, leaf.shape) for leaf in positional}
+    whole |= {hlo(leaf, leaf.shape[1:]) for leaf in positional if leaf.ndim > 2}
+    ops = [op for op in _top_level_ops(text) if op[1] in whole]
+    assert [op for op in ops if op[2] in ("copy", "fusion")] == []
+    assert sum(op[2] == "dynamic-update-slice" for op in ops) == len(positional)
 
 
 def test_decode_step_expert_casts_keep_their_scope_for_v5e(one_chip):
