@@ -25,6 +25,7 @@ ARCHS = (
     "hubert_xlarge",
     "qwen2_vl_2b",
     "deepseek_v2_lite",
+    "granite_4_0_h_small",
 )
 
 # canonical ids from the assignment (hyphens) -> module names
@@ -44,6 +45,9 @@ ALIASES.update(
         "deepseek-v2-lite": "deepseek_v2_lite",
         # one chip's share of it (configs/deepseek_v2_lite_ep8.py), not a cell arch
         "deepseek-v2-lite-ep8": "deepseek_v2_lite_ep8",
+        "granite-4.0-h-small": "granite_4_0_h_small",
+        # one chip's share of it (configs/granite_4_0_h_small_ep8.py), not a cell arch
+        "granite-4.0-h-small-ep8": "granite_4_0_h_small_ep8",
     }
 )
 
@@ -102,6 +106,9 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
         moe_held_offset=int(cfg.moe_held_offset * scale),
         ssm_d_state=8,
         ssm_dt_rank=8,
+        ssm_n_heads=4 if cfg.ssm_n_heads else 0,  # 4 heads of 32: the smoke d_inner 128
+        ssm_head_dim=32 if cfg.ssm_head_dim else 0,
+        ssm_chunk=8,
         mrope_sections=(2, 3, 3),
     )
 

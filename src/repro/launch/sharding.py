@@ -25,7 +25,7 @@ _COL = frozenset(
      "w_gate", "w_up", "conv_w", "unembed"}
 )
 _ROW = frozenset({"wo", "out_proj", "x_proj", "w_down", "A_log"})
-_VEC_MODEL = frozenset({"conv_b", "dt_bias", "D"})  # d_inner-length vectors
+_VEC_MODEL = frozenset({"conv_b", "dt_bias", "D", "ssm_norm"})  # d_inner or per-head vectors
 _EXPERT = frozenset({"moe_gate", "moe_up", "moe_down"})
 # The shared experts are replicated over the model axis like the router:
 # every expert-parallel shard computes them alike (models/moe.py).
@@ -48,7 +48,7 @@ def _fsdp_dim(shape: Tuple[int, ...], taken: int, dp: int) -> Optional[int]:
 
 _MAMBA_KEYS = frozenset(
     {"in_proj", "conv_w", "conv_b", "x_proj", "dt_proj", "dt_bias", "A_log",
-     "D", "out_proj"}
+     "D", "out_proj", "ssm_norm"}
 )
 
 

@@ -17,14 +17,14 @@ import jax
 import jax.numpy as jnp
 
 # Mixer kinds: how the sequence dimension is mixed.
-FULL, SWA, MLA, MAMBA = "full", "swa", "mla", "mamba"
+FULL, SWA, MLA, MAMBA, MAMBA2 = "full", "swa", "mla", "mamba", "mamba2"
 # MLP kinds.
 DENSE, MOE, NONE = "dense", "moe", "none"
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    mixer: str  # full | swa | mla | mamba
+    mixer: str  # full | swa | mla | mamba | mamba2
     mlp: str  # dense | moe | none
 
 
@@ -43,6 +43,7 @@ class ModelConfig:
     # attention details
     window: int = 4096  # SWA window
     attn_softcap: float = 0.0
+    attn_scale: float = 0.0  # softmax scale; 0: 1/sqrt(head_dim)
     logit_softcap: float = 0.0
     causal: bool = True
     rope_theta: float = 10000.0
@@ -82,6 +83,18 @@ class ModelConfig:
     ssm_d_conv: int = 4
     ssm_expand: int = 2
     ssm_dt_rank: int = 0
+    # Mamba-2 (SSD): heads of ssm_head_dim (ssm_n_heads x ssm_head_dim =
+    # d_inner), B and C shared by the heads of each of ssm_n_groups groups,
+    # the sequence pass scanned in chunks of ssm_chunk positions
+    ssm_n_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_n_groups: int = 1
+    ssm_chunk: int = 256
+    # granite's multipliers: embeddings times embedding_multiplier, each
+    # residual branch times residual_multiplier, logits over logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # misc
     norm_eps: float = 1e-6
     emb_scale: bool = False  # gemma: hidden *= sqrt(d_model)
@@ -125,6 +138,11 @@ class ModelConfig:
         return self.ssm_expand * self.d_model
 
     @property
+    def ssm_conv_dim(self) -> int:
+        """Mamba-2's convolved channels: x, then B and C of every group."""
+        return self.d_inner + 2 * self.ssm_n_groups * self.ssm_d_state
+
+    @property
     def dt_rank(self) -> int:
         return self.ssm_dt_rank or max(1, self.d_model // 16)
 
@@ -144,12 +162,13 @@ class ModelConfig:
 
     @property
     def attention_free(self) -> bool:
-        return all(s.mixer == MAMBA for s in self.layout)
+        return all(s.mixer in (MAMBA, MAMBA2) for s in self.layout)
 
     @property
     def subquadratic(self) -> bool:
         """Eligible for long_500k (SSM / hybrid-with-few-attn / pure-SWA)."""
-        return all(s.mixer in (MAMBA, SWA) for s in self.layout) or self.family == "hybrid"
+        return (all(s.mixer in (MAMBA, MAMBA2, SWA) for s in self.layout)
+                or self.family == "hybrid")
 
     def _layer_params(self, spec: LayerSpec) -> int:
         d, f = self.d_model, self.d_ff
@@ -176,6 +195,12 @@ class ModelConfig:
             n += self.dt_rank * di + di  # dt_proj, dt_bias
             n += di * self.ssm_d_state + di  # A_log, D
             n += di * d  # out_proj
+        elif spec.mixer == MAMBA2:
+            di, H, cd = self.d_inner, self.ssm_n_heads, self.ssm_conv_dim
+            n += d * (di + cd + H)  # in_proj: z, x B C, dt
+            n += self.ssm_d_conv * cd + cd  # conv_w, conv_b
+            n += 3 * H  # dt_bias, A_log, D
+            n += di + di * d  # ssm_norm, out_proj
         if spec.mlp == DENSE:
             n += (3 if self.activation in ("silu", "geglu") else 2) * d * f
         elif spec.mlp == MOE:
@@ -265,6 +290,22 @@ def init_layer_params(cfg: ModelConfig, spec: LayerSpec, key) -> Dict[str, Any]:
         ).astype(dt)
         p["D"] = jnp.ones((di,), dt)
         p["out_proj"] = _dense_init(next(ks), (di, d), dt)
+    elif spec.mixer == MAMBA2:
+        # The published Mamba-2 recipe: A = 1..H, dt log-uniform in
+        # [1e-3, 1e-1] through the inverse softplus, D = 1; the conv bias
+        # as a depthwise Conv1d draws it, uniform in +-1/sqrt(d_conv).
+        di, H, cd, dc = cfg.d_inner, cfg.ssm_n_heads, cfg.ssm_conv_dim, cfg.ssm_d_conv
+        p["in_proj"] = _dense_init(next(ks), (d, di + cd + H), dt)
+        p["conv_w"] = _dense_init(next(ks), (dc, cd), dt, scale=1.0 / math.sqrt(dc))
+        bound = 1.0 / math.sqrt(dc)
+        p["conv_b"] = jax.random.uniform(next(ks), (cd,), minval=-bound, maxval=bound).astype(dt)
+        dt0 = jnp.exp(jax.random.uniform(next(ks), (H,), minval=math.log(1e-3),
+                                         maxval=math.log(1e-1)))
+        p["dt_bias"] = (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dt)
+        p["A_log"] = jnp.log(jnp.arange(1, H + 1, dtype=jnp.float32)).astype(dt)
+        p["D"] = jnp.ones((H,), dt)
+        p["ssm_norm"] = jnp.ones((di,), dt)
+        p["out_proj"] = _dense_init(next(ks), (di, d), dt)
 
     if spec.mlp == DENSE:
         f = cfg.d_ff
@@ -309,10 +350,13 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
     keys = jax.random.split(key, cfg.period + 3)
     params: Dict[str, Any] = {
         # 1/sqrt(d) keeps tied-unembed logits O(1) at init (emb_scale archs
-        # multiply hidden states back up by sqrt(d)).
+        # multiply hidden states back up by sqrt(d)).  An embedding
+        # multiplier is divided out, so the embedded input keeps that scale:
+        # drawn at 1/sqrt(d), granite-4.0-h's x12 makes the input token's
+        # own tied logit outweigh whatever the random layers compute.
         "embed": _dense_init(
             keys[-1], (cfg.vocab_padded, cfg.d_model), cfg.param_dtype,
-            scale=1.0 / math.sqrt(cfg.d_model),
+            scale=1.0 / (math.sqrt(cfg.d_model) * cfg.embedding_multiplier),
         ),
         "final_ln": jnp.ones((cfg.d_model,), cfg.param_dtype),
     }

@@ -14,9 +14,10 @@ mixer they hand it:
 
 The cache is stacked over periods per layout position, so decode is also a
 single lax.scan (compile-size friendly at 512 devices).  Ring buffers handle
-SWA windows; MLA caches the compressed latent (its whole point); mamba keeps
-O(1) state.  The cache's format lives here alone: ``_layer_cache`` makes its
-leaves and ``cache_bytes_by_kind`` sizes them.
+SWA windows; MLA caches the compressed latent (its whole point); Mamba-1
+and Mamba-2 keep O(1) state, which their sequence mixers return.  The
+cache's format lives here alone: ``_layer_cache`` makes its leaves and
+``cache_bytes_by_kind`` sizes them.
 """
 from __future__ import annotations
 
@@ -31,8 +32,8 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from . import layers as L
-from .common import MAMBA, MLA, MOE, NONE, SWA, LayerSpec, ModelConfig
-from .mamba import (_ssm_scan_fused, causal_conv1d, mamba_decode, mamba_mixer_seq_parallel,
+from .common import MAMBA, MAMBA2, MLA, MOE, NONE, SWA, LayerSpec, ModelConfig
+from .mamba import (mamba2_decode, mamba2_sequence, mamba_decode, mamba_mixer_seq_parallel,
                     mamba_sequence)
 from .moe import EPInfo, moe_block
 
@@ -93,6 +94,8 @@ def embed_tokens(cfg: ModelConfig, params, batch: Dict[str, jnp.ndarray]) -> jnp
             x = jnp.concatenate([vis, x[:, n_vis:]], axis=1)
     if cfg.emb_scale:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), cfg.compute_dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, cfg.compute_dtype)
     return x
 
 
@@ -144,7 +147,10 @@ def unembed(cfg: ModelConfig, params, x: jnp.ndarray) -> jnp.ndarray:
         logits = x @ params["embed"].T.astype(cfg.compute_dtype)
     else:
         logits = x @ params["unembed"].astype(cfg.compute_dtype)
-    logits = L.softcap(logits.astype(jnp.float32), cfg.logit_softcap)
+    logits = logits.astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    logits = L.softcap(logits, cfg.logit_softcap)
     if cfg.vocab_padded != cfg.vocab:  # mask the padding columns exactly
         pad_ok = jnp.arange(cfg.vocab_padded) < cfg.vocab
         logits = jnp.where(pad_ok, logits, L.NEG_INF)
@@ -248,9 +254,11 @@ def _attn_seq(cfg, spec, p, h, cos, sin, ctx: ShardCtx):
     if cos is not None:
         q, k = L.apply_rope(q, cos, sin), L.apply_rope(k, cos, sin)
     window = cfg.window if spec.mixer == SWA else 0
+    scale = cfg.attn_scale or None  # None: 1/sqrt(head_dim)
     if _use_seq_parallel(ctx, S):
         out = _attention_seq_parallel(
-            q, k, v, ctx, causal=cfg.causal, window=window, cap=cfg.attn_softcap
+            q, k, v, ctx, causal=cfg.causal, window=window, cap=cfg.attn_softcap,
+            scale=scale,
         )
         return out.reshape(B, S, H * hd) @ p["wo"], (k, v)
     if ctx.mesh is not None and ctx.model_axis and not ctx.seq_shard:
@@ -262,7 +270,7 @@ def _attn_seq(cfg, spec, p, h, cos, sin, ctx: ShardCtx):
             k = ctx.constrain(k, P(b, None, ctx.model_axis, None))
             v = ctx.constrain(v, P(b, None, ctx.model_axis, None))
     out = L.attention(
-        q, k, v, causal=cfg.causal, window=window, cap=cfg.attn_softcap,
+        q, k, v, causal=cfg.causal, window=window, cap=cfg.attn_softcap, scale=scale,
         direct_threshold=(1 << 30) if ctx.unroll else 1024,
     )
     return out.reshape(B, S, H * hd) @ p["wo"], (k, v)
@@ -308,13 +316,15 @@ def _seq_mixer(cfg, spec, p, h, cos, sin, ctx: ShardCtx, with_cache: bool):
     if spec.mixer == MAMBA:
         if _use_seq_parallel(ctx, S):
             S_loc = S // ctx.mesh.shape[ctx.model_axis]
-            out = mamba_mixer_seq_parallel(
+            out, state = mamba_mixer_seq_parallel(
                 p, h, cfg, ctx, chunk=(S_loc if ctx.unroll else min(128, S_loc))
             )
         else:
-            out = mamba_sequence(p, h, cfg, chunk=(S if ctx.unroll else 128))
-        # the full-sequence mixer keeps no state: rebuild the final one
-        return out, (_mamba_prefill_state(cfg, p, h) if with_cache else None)
+            out, state = mamba_sequence(p, h, cfg, chunk=(S if ctx.unroll else 128))
+        return out, (state if with_cache else None)
+    if spec.mixer == MAMBA2:
+        out, state = mamba2_sequence(p, h, cfg, unroll=ctx.scan_unroll)
+        return out, (state if with_cache else None)
     if spec.mixer == MLA:
         out, (ckv, krope) = _mla_seq(cfg, p, h, cos, sin, ctx)
         return out, ({"ckv": ckv, "krope": krope} if with_cache else None)
@@ -356,6 +366,14 @@ def _mlp_apply(cfg, spec, p, h, ctx: ShardCtx):
         return L.mlp(p, h, cfg.activation)
 
 
+def _residual(cfg: ModelConfig, h):
+    """A residual branch's output as it joins the stream: times
+    ``cfg.residual_multiplier`` where the config has one."""
+    if cfg.residual_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.residual_multiplier, h.dtype)
+    return h
+
+
 def _layer(cfg, spec: LayerSpec, p, x, mix, ctx: ShardCtx, *, constrain: bool = True):
     """One layer in every mode, on weights in the compute dtype: pre-norm,
     the mode's mixer ``mix`` (normed hidden states -> (output, the layer's
@@ -368,7 +386,7 @@ def _layer(cfg, spec: LayerSpec, p, x, mix, ctx: ShardCtx, *, constrain: bool = 
         h, cache = mix(h)
     if cfg.sandwich_norm:
         h = L.rms_norm(h, p["post_ln1"], cfg.norm_eps)
-    x = x + h
+    x = x + _residual(cfg, h)
     if constrain:
         x = ctx.constrain(x, ctx.hidden_spec())
     if spec.mlp != NONE:
@@ -376,7 +394,7 @@ def _layer(cfg, spec: LayerSpec, p, x, mix, ctx: ShardCtx, *, constrain: bool = 
         h = _mlp_apply(cfg, spec, p, h, ctx)
         if cfg.sandwich_norm:
             h = L.rms_norm(h, p["post_ln2"], cfg.norm_eps)
-        x = x + h
+        x = x + _residual(cfg, h)
         if constrain:
             x = ctx.constrain(x, ctx.hidden_spec())
     return x, cache
@@ -503,6 +521,12 @@ def _layer_cache(cfg: ModelConfig, spec: LayerSpec, n: int, batch: int, max_seq:
             "h": jnp.zeros((n, batch, cfg.d_inner, cfg.ssm_d_state), jnp.float32),
             "conv": jnp.zeros((n, batch, cfg.ssm_d_conv - 1, cfg.d_inner), dt),
         }
+    if spec.mixer == MAMBA2:
+        return {
+            "h": jnp.zeros((n, batch, cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_d_state),
+                           jnp.float32),
+            "conv": jnp.zeros((n, batch, cfg.ssm_d_conv - 1, cfg.ssm_conv_dim), dt),
+        }
     if spec.mixer == MLA:
         return {
             "ckv": jnp.zeros((n, batch, max_seq, cfg.kv_lora_rank), dt),
@@ -565,7 +589,8 @@ def _attn_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx):
     kpos = jnp.where(jnp.arange(Sc) == slot, -1, cache["kpos"])
     attend = functools.partial(
         L.attention_partial, q, causal=True, window=cfg.window if spec.mixer == SWA else 0,
-        cap=cfg.attn_softcap, scale=1.0 / math.sqrt(hd), qpos=jnp.full((1, 1), pos),
+        cap=cfg.attn_softcap, scale=cfg.attn_scale or 1.0 / math.sqrt(hd),
+        qpos=jnp.full((1, 1), pos),
     )
     acc, m, l = attend(cache["k"], cache["v"], kpos=kpos[None, :])
     acc_new, m_new, l_new = attend(row["k"][:, None], row["v"][:, None],
@@ -628,6 +653,8 @@ def _decode_mixer(cfg, spec, p, h, cache, pos, cos, sin, ctx):
     a state layer's whole new state)."""
     if spec.mixer == MAMBA:
         return mamba_decode(p, h, cache, cfg)
+    if spec.mixer == MAMBA2:
+        return mamba2_decode(p, h, cache, cfg)
     if spec.mixer == MLA:
         return _mla_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx)
     return _attn_decode(cfg, spec, p, h, cache, pos, cos, sin, ctx)
@@ -639,7 +666,8 @@ def decode_step(
     """One decode step: tokens (B, 1) -> (logits (B, 1, V), new cache).
 
     Its ops carry the name scopes ``embed``, ``cast_params``, ``attention``
-    (with ``kv_update``, and ``latent_attention`` in MLA), ``moe`` (with
+    (with ``kv_update``, ``latent_attention`` in MLA and ``ssm_state`` in
+    Mamba-2), ``moe`` (with
     ``routed_experts`` and ``shared_expert``) or ``mlp``, and ``lm_head``:
     stable names for a profiler trace's per-op reduction."""
     pos = cache["pos"]
@@ -700,7 +728,7 @@ def _expand_prefill_cache(cfg: ModelConfig, layout, layer_caches, S: int, max_se
     """Grow prefill caches to max_seq decode slots, ring-aligned for SWA."""
     out = []
     for spec, c in zip(layout, layer_caches):
-        if spec.mixer == MAMBA:
+        if spec.mixer in (MAMBA, MAMBA2):
             out.append(c)
             continue
         if spec.mixer == MLA:
@@ -738,17 +766,3 @@ def _expand_prefill_cache(cfg: ModelConfig, layout, layer_caches, S: int, max_se
         out.append(c)
     return out
 
-
-def _mamba_prefill_state(cfg, p, u):
-    """Final (h, conv) state after a full sequence (for prefill->decode)."""
-    di, st, dr = cfg.d_inner, cfg.ssm_d_state, cfg.dt_rank
-    xz = u @ p["in_proj"]
-    x, _ = jnp.split(xz, 2, axis=-1)
-    conv_tail = x[:, -(cfg.ssm_d_conv - 1) :, :]
-    xc = jax.nn.silu(causal_conv1d(x, p["conv_w"], p["conv_b"]))
-    dbl = xc @ p["x_proj"]
-    dt, Bm, Cm = jnp.split(dbl, [dr, dr + st], axis=-1)
-    dt = jax.nn.softplus(dt @ p["dt_proj"] + p["dt_bias"])
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    _, h_last = _ssm_scan_fused(dt, xc, Bm, Cm, A)
-    return {"h": h_last, "conv": conv_tail}

@@ -141,11 +141,11 @@ def test_mini_mesh_cells_compile():
 
 def test_cell_applicability_table():
     cells = list(configs.all_cells())
-    assert len(cells) == 44  # 11 archs x 4 shapes
+    assert len(cells) == 48  # 12 archs x 4 shapes
     runnable = [c for c in cells if c[2]]
     # 9 documented skips (DESIGN.md §5); deepseek_v2_lite's is long_500k
     # (full attention: MLA is not sub-quadratic)
-    assert len(runnable) == 35
+    assert len(runnable) == 39
     skipped = {(a, s) for a, s, ok, _ in cells if not ok}
     assert ("deepseek_v2_lite", "long_500k") in skipped
     assert ("deepseek_v2_lite", "decode_32k") not in skipped
@@ -154,4 +154,5 @@ def test_cell_applicability_table():
     assert ("gemma_2b", "long_500k") in skipped
     assert ("falcon_mamba_7b", "long_500k") not in skipped
     assert ("jamba_v01_52b", "long_500k") not in skipped
+    assert ("granite_4_0_h_small", "long_500k") not in skipped  # hybrid: O(1) state
     assert ("h2o_danube3_4b", "long_500k") not in skipped

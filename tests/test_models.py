@@ -39,7 +39,8 @@ def test_smoke_forward_and_train_step(arch):
 @pytest.mark.parametrize(
     "arch",
     ["gemma2-2b", "minicpm3-4b", "falcon-mamba-7b", "jamba-v0.1-52b", "qwen2-vl-2b",
-     "h2o-danube-3-4b", "granite-moe-1b-a400m", "deepseek-v2-lite", "deepseek-v2-lite-ep8"],
+     "h2o-danube-3-4b", "granite-moe-1b-a400m", "deepseek-v2-lite", "deepseek-v2-lite-ep8",
+     "granite-4.0-h-small-ep8"],
 )
 def test_decode_matches_forward(arch):
     """prefill(S-1) + decode(last) must equal full forward's last logits.
@@ -71,7 +72,8 @@ def test_decode_matches_forward(arch):
     )
 
 
-@pytest.mark.parametrize("arch", ["granite_moe_1b_a400m", "falcon_mamba_7b"])
+@pytest.mark.parametrize("arch", ["granite_moe_1b_a400m", "falcon_mamba_7b",
+                                  "granite_4_0_h_small_ep8"])
 def test_compute_params_serve_bitwise(arch):
     """prefill and decode_step, compiled on compute_params' copy, give the
     same logits, caches and greedy tokens, bit for bit, as on the float32
@@ -219,7 +221,8 @@ def test_attention_chunked_matches_direct():
 
 
 def test_param_count_analytic_vs_actual():
-    for arch in ("gemma-2b", "granite-moe-1b-a400m", "falcon-mamba-7b"):
+    for arch in ("gemma-2b", "granite-moe-1b-a400m", "falcon-mamba-7b",
+                 "granite-4.0-h-small-ep8"):
         cfg = configs.smoke(arch)
         params = init_params(cfg, jax.random.key(0))
         actual = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
@@ -239,6 +242,7 @@ def test_full_config_param_counts():
         "h2o-danube-3-4b": (3.5e9, 4.6e9),
         "hubert-xlarge": (0.8e9, 1.3e9),
         "qwen2-vl-2b": (1.2e9, 2.3e9),
+        "granite-4.0-h-small": (31e9, 33e9),  # "32B-A9B"
     }
     for arch, (lo, hi) in expect.items():
         n = configs.get_config(arch).n_params()

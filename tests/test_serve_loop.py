@@ -46,7 +46,8 @@ def _read_then_dispatch(arch, prompts):
     return outs
 
 
-@pytest.mark.parametrize("arch", ["granite_moe_1b_a400m", "deepseek_v2_lite_ep8"])
+@pytest.mark.parametrize("arch", ["granite_moe_1b_a400m", "deepseek_v2_lite_ep8",
+                                  "granite_4_0_h_small_ep8"])
 def test_serve_tokens_match_a_read_then_dispatch_loop(monkeypatch, arch):
     import jax
 
